@@ -1,5 +1,7 @@
 """Pallas kernel micro-bench: wall time (interpret mode on CPU — semantics
-validation; Mosaic on TPU) and max deviation vs the pure-jnp oracle.
+validation, not speed; Mosaic on TPU) and max deviation vs the pure-jnp
+oracle.  Calls go through `kernels.ops`, which picks interpret mode from the
+backend.
 
 The fused dequant-attention rows additionally report the ISSUE's residency
 acceptance numbers: packed-resident contexts-per-byte vs fp-resident
@@ -18,11 +20,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
-from repro.kernels.decode_attention import (decode_attention,
-                                            decode_attention_quant)
-from repro.kernels.flash_attention import (flash_attention,
-                                           flash_attention_quant)
-from repro.kernels.kv_gather import kv_gather
+from repro.kernels.ops import (decode_attention_op as decode_attention,
+                               decode_attention_quant_op as
+                               decode_attention_quant,
+                               flash_attention_op as flash_attention,
+                               flash_attention_quant_op as
+                               flash_attention_quant,
+                               kv_gather_op as kv_gather)
 from repro.kernels.residency import (cache_bytes, composed_decode_hbm_traffic,
                                      fused_decode_hbm_reads, residency_ratio)
 
@@ -56,11 +60,9 @@ def run(smoke: bool = False) -> list[str]:
     q = jax.random.normal(KEY, (1, 4, 256, 64), jnp.float32)
     k = jax.random.normal(KEY, (1, 2, 256, 64), jnp.float32)
     v = jax.random.normal(KEY, (1, 2, 256, 64), jnp.float32)
-    out = flash_attention(q, k, v, causal=True, block_q=128, block_k=128,
-                          interpret=True)
+    out = flash_attention(q, k, v, causal=True, block_q=128, block_k=128)
     err = float(jnp.abs(out - ref.ref_flash_attention(q, k, v)).max())
-    wall = timeit(lambda: flash_attention(q, k, v, causal=True,
-                                          interpret=True), repeat=3)
+    wall = timeit(lambda: flash_attention(q, k, v, causal=True), repeat=3)
     flops = 4 * 256 * 256 * 4 * 64 / 2
     rows.append(row("kernel/flash_attn/256x4h", wall * 1e6,
                     f"max_err={err:.2e};flops={flops:.2e}"))
@@ -72,11 +74,11 @@ def run(smoke: bool = False) -> list[str]:
     kc = jax.random.normal(KEY, (4, S, 2, 64), jnp.float32)
     vc = jax.random.normal(KEY, (4, S, 2, 64), jnp.float32)
     lens = jnp.array([1000, 512, 64, S])
-    outd = decode_attention(qd, kc, vc, lens, block_s=256, interpret=True)
+    outd = decode_attention(qd, kc, vc, lens, block_s=256)
     errd = float(jnp.abs(outd
                          - ref.ref_decode_attention(qd, kc, vc, lens)).max())
-    walld = timeit(lambda: decode_attention(qd, kc, vc, lens, block_s=256,
-                                            interpret=True), repeat=3)
+    walld = timeit(lambda: decode_attention(qd, kc, vc, lens, block_s=256),
+                   repeat=3)
     rows.append(row("kernel/decode_attn/1k_ragged", walld * 1e6,
                     f"max_err={errd:.2e};cache_MB={kc.nbytes*2/1e6:.1f}"))
 
@@ -91,11 +93,11 @@ def run(smoke: bool = False) -> list[str]:
         qlens = jnp.array([Sq, Sq - G // 2])
         args = dict(bits=bits, group=group, chunk_tokens=G)
         outq = decode_attention_quant(qq, kq, vq, ks, vs, qlens, block_s=256,
-                                      interpret=True, **args)
+                                      **args)
         errq = float(jnp.abs(outq - ref.ref_decode_attention_quant(
             qq, kq, vq, ks, vs, qlens, **args)).max())
         wallq = timeit(lambda: decode_attention_quant(
-            qq, kq, vq, ks, vs, qlens, block_s=256, interpret=True, **args),
+            qq, kq, vq, ks, vs, qlens, block_s=256, **args),
             repeat=3)
         # the residency acceptance numbers for this shape (one layer, fp16
         # resident baseline)
@@ -112,21 +114,21 @@ def run(smoke: bool = False) -> list[str]:
         qp = jax.random.normal(KEY, (B, G, H, dh), jnp.float32)
         outf = flash_attention_quant(qp, kq, vq, ks, vs, causal=True,
                                      q_offset=Sq, block_q=G, block_k=256,
-                                     interpret=True, **args)
+                                     **args)
         errf = float(jnp.abs(outf - ref.ref_flash_attention_quant(
             qp, kq, vq, ks, vs, causal=True, q_offset=Sq, **args)).max())
         wallf = timeit(lambda: flash_attention_quant(
             qp, kq, vq, ks, vs, causal=True, q_offset=Sq, block_q=G,
-            block_k=256, interpret=True, **args), repeat=3)
+            block_k=256, **args), repeat=3)
         rows.append(row(f"kernel/flash_attn_quant/int{bits}", wallf * 1e6,
                         f"max_err={errf:.2e}"))
 
     # kv gather (ObjectCache on-device aggregation)
     pool = jax.random.normal(KEY, (256, 16, 256), jnp.float32)
     idx = jax.random.randint(KEY, (64,), 0, 256)
-    outg = kv_gather(pool, idx, interpret=True)
+    outg = kv_gather(pool, idx)
     errg = float(jnp.abs(outg - ref.ref_kv_gather(pool, idx)).max())
-    wallg = timeit(lambda: kv_gather(pool, idx, interpret=True), repeat=3)
+    wallg = timeit(lambda: kv_gather(pool, idx), repeat=3)
     rows.append(row("kernel/kv_gather/64of256", wallg * 1e6,
                     f"max_err={errg:.2e};bytes={outg.nbytes}"))
     return rows

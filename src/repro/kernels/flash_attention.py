@@ -12,10 +12,13 @@ no repeated-KV materialisation ever hits HBM.
 (DESIGN.md §Kernels): K/V block specs carry packed int8 / nibble-packed int4
 tiles plus per-chunk fp16 scale rows, expanded to fp32 by
 `kv_dequant.dequant_tile` inside the streaming kv loop.  It takes the
-serving engines' native [B, S, heads, dh] layout, runs all H heads per grid
-step (the packed tile is shared across the GQA group anyway), and can return
-the (m, l) softmax residuals so a caller can merge its output with attention
-over a disjoint key set — the engines' fp-resident suffix segment.
+serving engines' native [B, S, heads, dh] layout and regroups the queries
+outside the kernel so that, like the plain kernel, it puts the KV head on
+the grid: each step attends one [bk, dh] cache tile of one KV head (two for
+packed int4) with the [bq*rep, dh] rows of its GQA group, all 2-D.  It can
+return the (m, l) softmax residuals so a caller can merge its output with
+attention over a disjoint key set — the engines' fp-resident suffix
+segment.
 """
 from __future__ import annotations
 
@@ -27,13 +30,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .decode_attention import quant_block_s
-from .kv_dequant import dequant_tile
-
-# jax renamed TPUCompilerParams -> CompilerParams; accept either (see
-# decode_attention.py).
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
+from .decode_attention import (lane_block, online_update, quant_block_s,
+                               scale_block)
+from .kv_dequant import dequant_tile, int4_perm, int4_unperm, scale_bits
 
 NEG_INF = float("-inf")
 
@@ -121,7 +120,7 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, dh), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -133,9 +132,11 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
 def _quant_kernel(q_ref, kq_ref, vq_ref, ks_ref, vs_ref, o_ref, m_ref, l_ref,
                   m_scr, l_scr, acc_scr, *, causal: bool, sm_scale: float,
                   block_q: int, block_k: int, num_k: int, q_offset: int,
-                  bits: int, group: int):
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
+                  Sk: int, rep: int, hpb: int, dh: int, bits: int,
+                  group: int, narrow_cw):
+    lb = pl.program_id(1)
+    iq = pl.program_id(2)
+    ik = pl.program_id(3)
 
     @pl.when(ik == 0)
     def _init():
@@ -143,53 +144,44 @@ def _quant_kernel(q_ref, kq_ref, vq_ref, ks_ref, vs_ref, o_ref, m_ref, l_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q_start = q_offset + iq * block_q
     k_start = ik * block_k
-
     run = True
     if causal:
-        run = k_start <= q_start + block_q - 1
+        run = k_start <= q_offset + iq * block_q + block_q - 1
 
-    @pl.when(run if causal else True)
+    @pl.when(run)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)             # [bq, H, dh]
-        k = dequant_tile(kq_ref[0], ks_ref[0], bits=bits, group=group)
-        v = dequant_tile(vq_ref[0], vs_ref[0], bits=bits, group=group)
-        bq, H, dh = q.shape
-        KV = k.shape[1]
-        qg = q.reshape(bq, KV, H // KV, dh)
-        s = jnp.einsum("qkgd,skd->qkgs", qg, k) * sm_scale
-        s = s.reshape(bq, H, block_k)
+        col0 = lb * narrow_cw if narrow_cw else 0
+        args = dict(bits=bits, group=group, dh=dh, head0=lb * hpb, col0=col0)
+        ks = dequant_tile(kq_ref[...], ks_ref[...], **args)
+        vs = dequant_tile(vq_ref[...], vs_ref[...], **args)
+        R = q_ref.shape[1]  # block_q query positions x rep heads, s-major
+        cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (R, block_k), 1)
+        mask = cols < Sk  # ragged trailing kv block
         if causal:
-            rows = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 0)
-            cols = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 1)
-            s = jnp.where((rows >= cols)[:, None, :], s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2))
-        safe_m = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.where(jnp.isfinite(s), jnp.exp(s - safe_m[:, :, None]), 0.0)
-        alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - safe_m), 0.0)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=2)
-        pg = p.reshape(bq, KV, H // KV, block_k)
-        o = jnp.einsum("qkgs,skd->qkgd", pg, v).reshape(bq, H, dh)
-        acc_scr[...] = acc_scr[...] * alpha[:, :, None] + o
-        m_scr[...] = m_new
+            # row r is query position q_offset + r // rep, and
+            # r // rep >= n  <=>  r >= n * rep for integer n
+            rows = iq * R + jax.lax.broadcasted_iota(
+                jnp.int32, (R, block_k), 0)
+            mask = mask & (rows >= (cols - q_offset) * rep)
+        krows = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_k, dh), 0)
+        for h in range(hpb):
+            online_update(q_ref[h].astype(jnp.float32), ks[h],
+                          jnp.where(krows < Sk, vs[h], 0.0), mask, sm_scale,
+                          m_scr, l_scr, acc_scr, h)
 
     @pl.when(ik == num_k - 1)
     def _finalize():
         l = l_scr[...]
-        o_ref[0] = (acc_scr[...] /
-                    jnp.maximum(l, 1e-30)[:, :, None]).astype(o_ref.dtype)
-        m_ref[0] = m_scr[...]
-        l_ref[0] = l
+        o_ref[...] = (acc_scr[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        m_ref[...] = m_scr[...]
+        l_ref[...] = l
 
 
 def flash_attention_quant(q, k_q, v_q, k_scales, v_scales, *,
                           bits: int, group: int, chunk_tokens: int,
                           causal: bool = True, q_offset: int = 0,
-                          block_q: int = 128, block_k: int = 128,
+                          block_q: int = 128, block_k: int = 512,
                           return_residuals: bool = False,
                           interpret: bool = False):
     """Fused dequant + flash attention over a packed-resident prefix.
@@ -201,68 +193,95 @@ def flash_attention_quant(q, k_q, v_q, k_scales, v_scales, *,
     absolute position ``q_offset`` for the causal mask (suffix queries over a
     prefix cache).  Returns [B, Sq, H, dh], or (out, m [B, Sq, H],
     l [B, Sq, H]) with ``return_residuals``.
+
+    Any Sq works: the queries are padded to a whole number of ``block_q``
+    blocks outside the kernel (so VMEM use is bounded by the block, never
+    by the suffix length) and the padding is sliced off the outputs.
     """
     B, Sq, H, dh = q.shape
     Sk, KV, dhp = k_q.shape[1], k_q.shape[2], k_q.shape[3]
     assert dh == (2 * dhp if bits == 4 else dhp), (dh, dhp, bits)
     assert H % KV == 0
+    rep = H // KV
     G = chunk_tokens
     assert Sk % G == 0, (Sk, G)
-    NC = Sk // G
     ng = (KV * dh) // group
-    assert k_scales.shape == (B, NC, ng), (k_scales.shape, (B, NC, ng))
-    assert v_scales.shape == (B, NC, ng)
-    # Snap blocks to the actual extents: ragged query writes are not
-    # mask-coverable the way cache reads are, so block_q must divide Sq.
-    if Sq % block_q:
-        block_q = Sq
+    assert k_scales.shape == (B, Sk // G, ng), (k_scales.shape,
+                                                (B, Sk // G, ng))
+    assert v_scales.shape == (B, Sk // G, ng)
+    block_q = min(block_q, -(-Sq // 8) * 8)
+    Sqp = -(-Sq // block_q) * block_q
+    nq = Sqp // block_q
     block_k = quant_block_s(Sk, G, block_k)
-    if Sk % block_k:
-        block_k = G
-    assert Sq % block_q == 0 and Sk % block_k == 0
-    nq, nk = Sq // block_q, Sk // block_k
-    cpb = max(1, block_k // G)
+    nk = -(-Sk // block_k)  # ragged tail masked in-kernel
+    ncb = max(1, block_k // G)
     stride = max(1, G // block_k)
-    sm_scale = 1.0 / math.sqrt(dh)
+    hpb, wq = lane_block(KV, dh, bits)
+    cw, narrow = scale_block(KV, dh, group, hpb)
+    R = block_q * rep
 
-    kernel = functools.partial(_quant_kernel, causal=causal,
-                               sm_scale=sm_scale, block_q=block_q,
-                               block_k=block_k, num_k=nk, q_offset=q_offset,
-                               bits=bits, group=group)
+    # [B, Sq, H, dh] -> [B, KV, Sqp*rep, dh]: each KV head's query rows,
+    # position-major, so a block of rows is a block of positions
+    qk = jnp.pad(q, ((0, 0), (0, Sqp - Sq), (0, 0), (0, 0)))
+    qk = qk.reshape(B, Sqp, KV, rep, dh).transpose(0, 2, 1, 3, 4)
+    qk = qk.reshape(B, KV, Sqp * rep, dh)
+    if bits == 4:
+        qk = int4_perm(qk)
 
-    def scale_idx(b, iq, ik):
-        del iq
-        return (b, ik if stride == 1 else ik // stride, 0)
+    kernel = functools.partial(
+        _quant_kernel, causal=causal, sm_scale=1.0 / math.sqrt(dh),
+        block_q=block_q, block_k=block_k, num_k=nk, q_offset=q_offset, Sk=Sk,
+        rep=rep, hpb=hpb, dh=dh, bits=bits, group=group,
+        narrow_cw=cw if narrow else None)
+
+    def row_idx(b, lb, iq, ik):
+        return (b, lb, iq, 0)
+
+    def cache_idx(b, lb, iq, ik):
+        return (b, ik, lb)
+
+    def scale_idx(b, lb, iq, ik):
+        return (b, ik // stride, lb if narrow else 0)
 
     out, m, l = pl.pallas_call(
         kernel,
-        grid=(B, nq, nk),
+        grid=(B, KV // hpb, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, H, dh), lambda b, iq, ik: (b, iq, 0, 0)),
-            pl.BlockSpec((1, block_k, KV, dhp),
-                         lambda b, iq, ik: (b, ik, 0, 0)),
-            pl.BlockSpec((1, block_k, KV, dhp),
-                         lambda b, iq, ik: (b, ik, 0, 0)),
-            pl.BlockSpec((1, cpb, ng), scale_idx),
-            pl.BlockSpec((1, cpb, ng), scale_idx),
+            pl.BlockSpec((None, hpb, R, dh), row_idx),
+            pl.BlockSpec((None, block_k, wq), cache_idx),
+            pl.BlockSpec((None, block_k, wq), cache_idx),
+            pl.BlockSpec((None, ncb, cw), scale_idx),
+            pl.BlockSpec((None, ncb, cw), scale_idx),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, H, dh), lambda b, iq, ik: (b, iq, 0, 0)),
-            pl.BlockSpec((1, block_q, H), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, block_q, H), lambda b, iq, ik: (b, iq, 0)),
+            pl.BlockSpec((None, hpb, R, dh), row_idx),
+            pl.BlockSpec((None, hpb, R, 1), row_idx),
+            pl.BlockSpec((None, hpb, R, 1), row_idx),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Sq, H, dh), q.dtype),
-            jax.ShapeDtypeStruct((B, Sq, H), jnp.float32),
-            jax.ShapeDtypeStruct((B, Sq, H), jnp.float32),
+            jax.ShapeDtypeStruct((B, KV, Sqp * rep, dh), q.dtype),
+            jax.ShapeDtypeStruct((B, KV, Sqp * rep, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, KV, Sqp * rep, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, H), jnp.float32),
-            pltpu.VMEM((block_q, H), jnp.float32),
-            pltpu.VMEM((block_q, H, dh), jnp.float32),
+            pltpu.VMEM((hpb, R, 1), jnp.float32),
+            pltpu.VMEM((hpb, R, 1), jnp.float32),
+            pltpu.VMEM((hpb, R, dh), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
-    )(q, k_q, v_q, k_scales, v_scales)
-    return (out, m, l) if return_residuals else out
+    )(qk, k_q.reshape(B, Sk, KV * dhp), v_q.reshape(B, Sk, KV * dhp),
+      scale_bits(k_scales), scale_bits(v_scales))
+    if bits == 4:
+        out = int4_unperm(out)
+    out = out.reshape(B, KV, Sqp, rep, dh).transpose(0, 2, 1, 3, 4)
+    out = out.reshape(B, Sqp, H, dh)[:, :Sq]
+    if not return_residuals:
+        return out
+
+    def rows_to_heads(a):  # [B, KV, Sqp*rep, 1] -> [B, Sq, H]
+        a = a.reshape(B, KV, Sqp, rep).transpose(0, 2, 1, 3)
+        return a.reshape(B, Sqp, H)[:, :Sq]
+    return out, rows_to_heads(m), rows_to_heads(l)

@@ -17,12 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept either so the
-# kernels (and the interpret-mode capability probe keyed on this one) work
-# across jax versions.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
 
 def _kernel(idx_ref, pool_ref, out_ref):
     # The index indirection is entirely inside the BlockSpec index_map; the
@@ -49,7 +43,7 @@ def kv_gather(pool, indices, *, interpret: bool = False) -> jnp.ndarray:
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, G, W), pool.dtype),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(indices, pool)

@@ -1,16 +1,14 @@
 """Jitted public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode — the kernel
-body runs in Python per grid step, which validates the tiling and semantics;
-on TPU backends they compile to Mosaic.  ``interpret`` is resolved once per
-call site by `_default_interpret()` unless overridden — every op here goes
-through that single probe, so the `REPRO_PALLAS_INTERPRET` env override
-below governs the whole kernel surface uniformly.
+The kernels compile to Mosaic on a TPU.  On the CPU backend (tests, CPU-only
+installations) they run in interpret mode — the kernel body runs per grid
+step, which validates the tiling and semantics.  `_interpret()` is that one
+rule; no option or environment variable overrides it, and a kernel that
+fails to compile raises.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -23,146 +21,64 @@ from .kv_dequant import kv_dequant as _dequant
 from .kv_dequant import kv_dequant_packed4 as _dequant_p4
 from .kv_gather import kv_gather as _gather
 
-_TRUTHY = frozenset({"1", "true", "yes", "on"})
-_FALSY = frozenset({"0", "false", "no", "off"})
+
+def _interpret() -> bool:
+    return jax.default_backend() == "cpu"
 
 
-def _default_interpret() -> bool:
-    """One probe for every op: interpret off on real TPU backends, on
-    everywhere else, with `REPRO_PALLAS_INTERPRET=1|0` as an explicit
-    override (read per call so tests can monkeypatch the environment)."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET", "").strip().lower()
-    if env in _TRUTHY:
-        return True
-    if env in _FALSY:
-        return False
-    return jax.default_backend() != "tpu"
-
-
-@functools.cache
-def dequant_supported(fused: bool = False) -> bool:
-    """Capability probe for the dequant kernels (run once per flavor, cached).
-
-    Mirrors the test-suite probe: actually execute a trivial call rather than
-    sniff versions.  The standalone dequant kernels avoid the Pallas-TPU-only
-    API surface, so they normally pass even on CPU-only builds (interpret
-    mode); the serving client falls back to the numpy reference when they
-    don't.  Probes the group-wise scale path too — a build where only the
-    grouped broadcast fails must fall back for every codec rather than crash
-    on the first gw/mixed payload.
-
-    ``fused=True`` additionally probes the fused quantized-KV *attention*
-    kernels (decode + flash, int8 and packed-int4, grouped scales) — they
-    touch more of the Pallas surface (scalar prefetch, compiler params,
-    multi-output), so a build can support standalone dequant but not fusion;
-    the engines then stay on the composed path."""
-    try:
-        q = jnp.zeros((1, 2, 4), jnp.int8)
-        qp = jnp.zeros((1, 2, 2), jnp.uint8)
-        s = jnp.ones((1, 4), jnp.float16)
-        sg = jnp.ones((1, 2), jnp.float16)
-        kv_dequant_op(q, s)
-        kv_dequant_packed4_op(qp, s)
-        kv_dequant_op(q, sg, group=2)
-        kv_dequant_packed4_op(qp, sg, group=2)
-        if not fused:
-            return True
-        # B=1, H=2, KV=1, dh=4 (W=4), S=8, chunk_tokens=4, group=2
-        qd = jnp.zeros((1, 2, 4), jnp.float32)
-        k8 = jnp.zeros((1, 8, 1, 4), jnp.int8)
-        k4 = jnp.zeros((1, 8, 1, 2), jnp.uint8)
-        sc = jnp.ones((1, 2, 2), jnp.float16)
-        ln = jnp.array([8], jnp.int32)
-        decode_attention_quant_op(qd, k8, k8, sc, sc, ln, bits=8, group=2,
-                                  chunk_tokens=4, block_s=4)
-        decode_attention_quant_op(qd, k4, k4, sc, sc, ln, bits=4, group=2,
-                                  chunk_tokens=4, block_s=4)
-        qf = jnp.zeros((1, 4, 2, 4), jnp.float32)
-        flash_attention_quant_op(qf, k8, k8, sc, sc, bits=8, group=2,
-                                 chunk_tokens=4, causal=True, q_offset=4,
-                                 block_q=4, block_k=4)
-        flash_attention_quant_op(qf, k4, k4, sc, sc, bits=4, group=2,
-                                 chunk_tokens=4, causal=False,
-                                 block_q=4, block_k=4)
-        return True
-    except Exception:  # pragma: no cover - environment dependent
-        return False
-
-
-def fused_attention_supported() -> bool:
-    """Can this build run the fused quantized-KV attention kernels?"""
-    return dequant_supported(fused=True)
-
-
-@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))
 def flash_attention_op(q, k, v, *, causal: bool = True, block_q: int = 128,
-                       block_k: int = 128, interpret: bool | None = None):
-    interpret = _default_interpret() if interpret is None else interpret
+                       block_k: int = 128):
     return _flash(q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-                  interpret=interpret)
+                  interpret=_interpret())
 
 
-@functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
-def decode_attention_op(q, k_cache, v_cache, lengths, *, block_s: int = 512,
-                        interpret: bool | None = None):
-    interpret = _default_interpret() if interpret is None else interpret
+@functools.partial(jax.jit, static_argnames=("block_s",))
+def decode_attention_op(q, k_cache, v_cache, lengths, *, block_s: int = 512):
     return _decode(q, k_cache, v_cache, lengths, block_s=block_s,
-                   interpret=interpret)
+                   interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "bits", "group", "chunk_tokens", "block_s", "return_residuals",
-    "interpret"))
+    "bits", "group", "chunk_tokens", "block_s", "return_residuals"))
 def decode_attention_quant_op(q, k_q, v_q, k_scales, v_scales, lengths, *,
                               bits: int, group: int, chunk_tokens: int,
                               block_s: int = 512,
-                              return_residuals: bool = False,
-                              interpret: bool | None = None):
-    interpret = _default_interpret() if interpret is None else interpret
+                              return_residuals: bool = False):
     return _decode_quant(q, k_q, v_q, k_scales, v_scales, lengths, bits=bits,
                          group=group, chunk_tokens=chunk_tokens,
                          block_s=block_s, return_residuals=return_residuals,
-                         interpret=interpret)
+                         interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=(
     "bits", "group", "chunk_tokens", "causal", "q_offset", "block_q",
-    "block_k", "return_residuals", "interpret"))
+    "block_k", "return_residuals"))
 def flash_attention_quant_op(q, k_q, v_q, k_scales, v_scales, *, bits: int,
                              group: int, chunk_tokens: int,
                              causal: bool = True, q_offset: int = 0,
-                             block_q: int = 128, block_k: int = 128,
-                             return_residuals: bool = False,
-                             interpret: bool | None = None):
-    interpret = _default_interpret() if interpret is None else interpret
+                             block_q: int = 128, block_k: int = 512,
+                             return_residuals: bool = False):
     return _flash_quant(q, k_q, v_q, k_scales, v_scales, bits=bits,
                         group=group, chunk_tokens=chunk_tokens, causal=causal,
                         q_offset=q_offset, block_q=block_q, block_k=block_k,
                         return_residuals=return_residuals,
-                        interpret=interpret)
+                        interpret=_interpret())
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def kv_gather_op(pool, indices, *, interpret: bool | None = None):
-    interpret = _default_interpret() if interpret is None else interpret
-    return _gather(pool, indices, interpret=interpret)
+@jax.jit
+def kv_gather_op(pool, indices):
+    return _gather(pool, indices, interpret=_interpret())
 
 
-@functools.partial(jax.jit, static_argnames=("group", "out_dtype",
-                                             "interpret"))
-def kv_dequant_op(q, scales, *, group: int = 1, out_dtype=jnp.float32,
-                  interpret: bool | None = None):
-    interpret = _default_interpret() if interpret is None else interpret
+@functools.partial(jax.jit, static_argnames=("group", "out_dtype"))
+def kv_dequant_op(q, scales, *, group: int = 1, out_dtype=jnp.float32):
     return _dequant(q, scales, group=group, out_dtype=out_dtype,
-                    interpret=interpret)
+                    interpret=_interpret())
 
 
-@functools.partial(jax.jit, static_argnames=("group", "out_dtype",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("group", "out_dtype"))
 def kv_dequant_packed4_op(q_packed, scales, *, group: int = 1,
-                          out_dtype=jnp.float32,
-                          interpret: bool | None = None):
-    interpret = _default_interpret() if interpret is None else interpret
+                          out_dtype=jnp.float32):
     return _dequant_p4(q_packed, scales, group=group, out_dtype=out_dtype,
-                       interpret=interpret)
+                       interpret=_interpret())

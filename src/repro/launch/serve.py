@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.configs import get_config, get_smoke_config
 from repro.core import Gateway, InMemoryStore, Policy, RadixIndex
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 from repro.serving import Orchestrator, ServingEngine
 from repro.serving.orchestrator import StragglerModel
@@ -40,6 +41,7 @@ def main() -> None:
     ap.add_argument("--hedge", action="store_true")
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
     params = model.init_params(jax.random.PRNGKey(0))

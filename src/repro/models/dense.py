@@ -68,27 +68,24 @@ def decode_block(p, cfg: ModelConfig, x, k_cache, v_cache, pos):
 
 
 def block_packed(p, cfg: ModelConfig, x, positions, packed_kv, *, bits: int,
-                 group: int, chunk_tokens: int, use_fused: bool,
-                 interpret=None):
+                 group: int, chunk_tokens: int):
     """`block` with a quantized-resident prefix (see
     `layers.attention_packed_prefix`); returns (x, (k, v) of this suffix)."""
     h, seg_kv = nn.attention_packed_prefix(
         p["attn"], cfg, nn.rmsnorm(p["ln1"], x), packed_kv,
         positions=positions, bits=bits, group=group,
-        chunk_tokens=chunk_tokens, use_fused=use_fused, interpret=interpret)
+        chunk_tokens=chunk_tokens)
     x = x + h
     x = x + nn.mlp(p["mlp"], nn.rmsnorm(p["ln2"], x), cfg.mlp_kind)
     return x, seg_kv
 
 
 def decode_block_packed(p, cfg: ModelConfig, x, packed_kv, sk_cache, sv_cache,
-                        pos, *, bits: int, group: int, chunk_tokens: int,
-                        use_fused: bool, interpret=None):
+                        pos, *, bits: int, group: int, chunk_tokens: int):
     """`decode_block` against packed prefix + fp suffix cache."""
     h, (sk_cache, sv_cache) = nn.decode_attention_packed_prefix(
         p["attn"], cfg, nn.rmsnorm(p["ln1"], x), packed_kv, sk_cache,
-        sv_cache, pos, bits=bits, group=group, chunk_tokens=chunk_tokens,
-        use_fused=use_fused, interpret=interpret)
+        sv_cache, pos, bits=bits, group=group, chunk_tokens=chunk_tokens)
     x = x + h
     x = x + nn.mlp(p["mlp"], nn.rmsnorm(p["ln2"], x), cfg.mlp_kind)
     return x, sk_cache, sv_cache

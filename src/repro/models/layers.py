@@ -311,24 +311,20 @@ def merge_attention_partials(parts):
 
 
 def attention_packed_prefix(p, cfg: ModelConfig, x, packed_kv, *, positions,
-                            bits: int, group: int, chunk_tokens: int,
-                            use_fused: bool, interpret=None):
+                            bits: int, group: int, chunk_tokens: int):
     """Suffix attention over a *quantized-resident* prefix (prefill form).
 
     ``packed_kv``: (k_q, v_q, k_scales, v_scales) — the wire image of the
     prefix as `serving.kv_chunks.PackedLayerKV.as_tuple()` yields it (passed
     as a bare tuple so this module never imports the serving layer).  The
-    prefix half runs the fused `flash_attention_quant` kernel when
-    ``use_fused`` (capability-probed by the caller), else the composed
-    `ref_dequant_cache` + `attention_partials` fallback; the suffix half is
-    ordinary causal attention over this segment's own fp KV; the two merge
-    exactly via the softmax residuals.  Requires ``cfg.logit_softcap == 0``
-    (the fused kernels don't implement softcap).
+    prefix half runs the fused `flash_attention_quant` kernel; the suffix
+    half is ordinary causal attention over this segment's own fp KV; the two
+    merge exactly via the softmax residuals.  Requires
+    ``cfg.logit_softcap == 0`` (the fused kernels don't implement softcap).
 
     Returns (out [B,S,d], seg_kv) exactly like `attention`.
     """
     from repro.kernels import ops as kernel_ops
-    from repro.kernels.ref import ref_dequant_cache
 
     B, S, _ = x.shape
     H, KV, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -338,26 +334,15 @@ def attention_packed_prefix(p, cfg: ModelConfig, x, packed_kv, *, positions,
     q = rope(q, positions, cfg.rope_theta)
     k = rope(k, positions, cfg.rope_theta)
     seg_kv = (k, v)
-    P = k_q.shape[1]
     if k_q.shape[0] != B:
         k_q, v_q, k_scales, v_scales = (
             jnp.broadcast_to(a, (B,) + a.shape[1:])
             for a in (k_q, v_q, k_scales, v_scales))
-    if use_fused:
-        # every prefix position precedes every suffix query: non-causal
-        o_p, m_p, l_p = kernel_ops.flash_attention_quant_op(
-            q, k_q, v_q, k_scales, v_scales, bits=bits, group=group,
-            chunk_tokens=chunk_tokens, causal=False, return_residuals=True,
-            interpret=interpret)
-        o_p = o_p.astype(jnp.float32)
-    else:
-        kf = ref_dequant_cache(k_q, k_scales, bits=bits, group=group,
-                               chunk_tokens=chunk_tokens)
-        vf = ref_dequant_cache(v_q, v_scales, bits=bits, group=group,
-                               chunk_tokens=chunk_tokens)
-        o_p, m_p, l_p = attention_partials(
-            q.astype(jnp.float32), _repeat_kv(kf, H // KV),
-            _repeat_kv(vf, H // KV), jnp.ones((1, 1, S, P), bool))
+    # every prefix position precedes every suffix query: non-causal
+    o_p, m_p, l_p = kernel_ops.flash_attention_quant_op(
+        q, k_q, v_q, k_scales, v_scales, bits=bits, group=group,
+        chunk_tokens=chunk_tokens, causal=False, return_residuals=True)
+    o_p = o_p.astype(jnp.float32)
     iq = jnp.arange(S)[:, None]
     mask = (jnp.arange(S)[None, :] <= iq)[None, None]
     kr = _repeat_kv(k, H // KV).astype(jnp.float32)
@@ -370,18 +355,16 @@ def attention_packed_prefix(p, cfg: ModelConfig, x, packed_kv, *, positions,
 
 def decode_attention_packed_prefix(p, cfg: ModelConfig, x, packed_kv,
                                    sk_cache, sv_cache, pos, *, bits: int,
-                                   group: int, chunk_tokens: int,
-                                   use_fused: bool, interpret=None):
+                                   group: int, chunk_tokens: int):
     """One-token attention over packed prefix + fp suffix cache.
 
     The decode form of `attention_packed_prefix`: the prefix stays
-    quantized-resident (read by the fused `decode_attention_quant` kernel or
-    the composed fallback); only this request's *suffix* lives in an fp
+    quantized-resident (read by the fused `decode_attention_quant`
+    kernel); only this request's *suffix* lives in an fp
     cache [B, S_suf, KV, dh], written at ``pos - P`` like
     `decode_attention` writes at ``pos``.  Returns (out [B,1,d],
     (sk_cache, sv_cache))."""
     from repro.kernels import ops as kernel_ops
-    from repro.kernels.ref import ref_dequant_cache
 
     B = x.shape[0]
     H, KV, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -403,22 +386,12 @@ def decode_attention_packed_prefix(p, cfg: ModelConfig, x, packed_kv,
         k_q, v_q, k_scales, v_scales = (
             jnp.broadcast_to(a, (B,) + a.shape[1:])
             for a in (k_q, v_q, k_scales, v_scales))
-    if use_fused:
-        lengths = jnp.full((B,), P, jnp.int32)
-        o_p, m_p, l_p = kernel_ops.decode_attention_quant_op(
-            q[:, 0], k_q, v_q, k_scales, v_scales, lengths, bits=bits,
-            group=group, chunk_tokens=chunk_tokens, return_residuals=True,
-            interpret=interpret)
-        o_p = o_p.astype(jnp.float32)[:, None]  # [B,1,H,dh]
-        m_p, l_p = m_p[:, None], l_p[:, None]
-    else:
-        kf = ref_dequant_cache(k_q, k_scales, bits=bits, group=group,
-                               chunk_tokens=chunk_tokens)
-        vf = ref_dequant_cache(v_q, v_scales, bits=bits, group=group,
-                               chunk_tokens=chunk_tokens)
-        o_p, m_p, l_p = attention_partials(
-            q.astype(jnp.float32), _repeat_kv(kf, H // KV),
-            _repeat_kv(vf, H // KV), jnp.ones((1, 1, 1, P), bool))
+    lengths = jnp.full((B,), P, jnp.int32)
+    o_p, m_p, l_p = kernel_ops.decode_attention_quant_op(
+        q[:, 0], k_q, v_q, k_scales, v_scales, lengths, bits=bits,
+        group=group, chunk_tokens=chunk_tokens, return_residuals=True)
+    o_p = o_p.astype(jnp.float32)[:, None]  # [B,1,H,dh]
+    m_p, l_p = m_p[:, None], l_p[:, None]
     Ss = sk_cache.shape[1]
     mask = (jnp.arange(Ss)[None, :] <= spos[:, None])[:, None, None, :]
     o_s, m_s, l_s = attention_partials(

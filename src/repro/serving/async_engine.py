@@ -51,7 +51,6 @@ import numpy as np
 from repro.codec import get_codec
 from repro.core import Delivery
 from repro.core.hashing import chunk_keys
-from repro.kernels import ops as kernel_ops
 from repro.core.transport import (LOCAL_DRAM, RDMA_SESSION_SETUP_S,
                                   S3_RDMA_AGG, TransportProfile, VirtualClock)
 from repro.cluster.events import Event, EventKind, EventQueue
@@ -207,8 +206,8 @@ class AsyncEngine:
                               or (self.cfg.family == "moe"
                                   and self.cfg.moe_every == 1))
         # same residency contract as ServingEngine: "packed" keeps layerwise
-        # prefixes quantized-resident through prefill (fused dequant-attention
-        # or the composed fallback); the ContinuousBatcher pools sequences
+        # prefixes quantized-resident through prefill (fused dequant-
+        # attention); the ContinuousBatcher pools sequences
         # into one fp cache, so a packed prefix entering decode is expanded
         # exactly once at the `packed_layer_to_fp` boundary.
         if kv_resident not in ("fp", "packed"):
@@ -228,7 +227,6 @@ class AsyncEngine:
                                  "logit_softcap == 0 (fused kernels don't "
                                  "implement softcap)")
         self.kv_resident = kv_resident
-        self._use_fused = kernel_ops.dequant_supported(fused=True)
         self.batcher: Optional[ContinuousBatcher] = None
         self.peak_transfers = 0  # max concurrently in-flight fetches observed
 
@@ -534,8 +532,7 @@ class AsyncEngine:
             x, sk, sv = self.runner._layer_packed(
                 self.runner.layer_params(l), fl.x, pkv.as_tuple(),
                 fl.positions, bits=pkv.bits, group=pkv.group,
-                chunk_tokens=pkv.chunk_tokens, use_fused=self._use_fused,
-                interpret=None)
+                chunk_tokens=pkv.chunk_tokens)
             fl.x = jax.block_until_ready(x)
             t2 = time.perf_counter()
             fl.wall_compute_s += t2 - t1

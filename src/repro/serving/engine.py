@@ -42,7 +42,6 @@ from repro.models import layers as nn
 from repro.obs.metrics import MetricsRegistry
 
 from repro.codec import get_codec
-from repro.kernels import ops as kernel_ops
 
 from .kv_chunks import (cache_to_chunks, layer_payload_to_device_kv,
                         layer_payload_to_kv, layer_payload_to_packed_kv)
@@ -119,17 +118,14 @@ class ModelRunner:
             return nn.logits(params["embed"], cfg, h)[:, 0, :]
 
         def layer_packed_fn(layer_p, x, packed_kv, positions, *, bits, group,
-                            chunk_tokens, use_fused, interpret):
+                            chunk_tokens):
             h, seg = dense.block_packed(layer_p, cfg, x, positions, packed_kv,
                                         bits=bits, group=group,
-                                        chunk_tokens=chunk_tokens,
-                                        use_fused=use_fused,
-                                        interpret=interpret)
+                                        chunk_tokens=chunk_tokens)
             return h, seg[0], seg[1]
 
         def decode_packed_fn(params, packed_all, sk_cache, sv_cache, token,
-                             pos, *, bits_map, group_map, chunk_tokens,
-                             use_fused, interpret):
+                             pos, *, bits_map, group_map, chunk_tokens):
             # Python-unrolled layer loop: per-layer bits/groups are static
             # (mixed-bit codecs give layers different packed dtypes/shapes),
             # which rules out a lax.scan over a stacked cache.
@@ -140,8 +136,7 @@ class ModelRunner:
                 x, k_c, v_c = dense.decode_block_packed(
                     layer_p, cfg, x, packed_all[l], sk_cache[l], sv_cache[l],
                     pos, bits=bits_map[l], group=group_map[l],
-                    chunk_tokens=chunk_tokens, use_fused=use_fused,
-                    interpret=interpret)
+                    chunk_tokens=chunk_tokens)
                 new_k.append(k_c)
                 new_v.append(v_c)
             x = nn.rmsnorm(params["final_norm"], x)
@@ -159,12 +154,10 @@ class ModelRunner:
         self._decode = jax.jit(lambda p, c, t, pos:
                                model.decode_step(p, c, t, pos))
         self._layer_packed = jax.jit(
-            layer_packed_fn, static_argnames=("bits", "group", "chunk_tokens",
-                                              "use_fused", "interpret"))
+            layer_packed_fn, static_argnames=("bits", "group", "chunk_tokens"))
         self._decode_packed = jax.jit(
             decode_packed_fn, static_argnames=("bits_map", "group_map",
-                                               "chunk_tokens", "use_fused",
-                                               "interpret"))
+                                               "chunk_tokens"))
 
     def layer_params(self, l: int):
         return jax.tree.map(lambda a: a[l], self.params["layers"])
@@ -196,8 +189,7 @@ class ServingEngine:
         # "fp" expands fetched prefixes to model width on arrival (the
         # historical path); "packed" keeps them quantized-resident and
         # dispatches the fused dequant-attention kernels (DESIGN.md
-        # §Kernels), falling back to the composed jnp path when the build
-        # fails the fused capability probe.
+        # §Kernels).
         if kv_resident not in ("fp", "packed"):
             raise ValueError(f"kv_resident must be 'fp' or 'packed', "
                              f"got {kv_resident!r}")
@@ -215,7 +207,6 @@ class ServingEngine:
                                  "logit_softcap == 0 (fused kernels don't "
                                  "implement softcap)")
         self.kv_resident = kv_resident
-        self._use_fused = kernel_ops.dequant_supported(fused=True)
         self._last_packed = None
         # one registry per serving stack: default to the orchestrator's so
         # engine + orch counters snapshot as a single consistent cut
@@ -368,8 +359,8 @@ class ServingEngine:
         segs_k, segs_v, compute_times = [], [], []
         for l in range(cfg.num_layers):
             # wait for the layer-ready notification (virtual transfer clock);
-            # quantized payloads dequantize on device (fused Pallas kernel
-            # when available), identity payloads are a bit view
+            # quantized payloads dequantize on device (fused Pallas kernel),
+            # identity payloads are a bit view
             if tracer is not None:
                 with tracer.span(req_id, "dequant", cat="engine", layer=l):
                     k_d, v_d = layer_payload_to_device_kv(
@@ -412,7 +403,7 @@ class ServingEngine:
         Each layer's payload is uploaded as its wire image
         (`layer_payload_to_packed_kv` — packed ints + fp16 scale rows, no
         standalone dequant pass) and attention reads it through the fused
-        kernels (or the composed jnp fallback).  Only this request's suffix
+        kernels.  Only this request's suffix
         KV is ever materialized at model width, so HBM residency for the
         reused prefix is wire-sized end to end, and the suffix is all the
         engine needs to commit (prefix chunks are already content-addressed
@@ -439,8 +430,7 @@ class ServingEngine:
             t0 = time.perf_counter()
             x, sk, sv = self.runner._layer_packed(
                 self._layer_params(l), x, pkv.as_tuple(), positions,
-                bits=pkv.bits, group=pkv.group, chunk_tokens=pkv.chunk_tokens,
-                use_fused=self._use_fused, interpret=None)
+                bits=pkv.bits, group=pkv.group, chunk_tokens=pkv.chunk_tokens)
             x = jax.block_until_ready(x)
             dt = time.perf_counter() - t0
             compute_times.append(dt)
@@ -607,8 +597,7 @@ class ServingEngine:
             lg, sk, sv = self.runner._decode_packed(
                 self.params, packed_all, sk, sv,
                 jnp.asarray([[tok]], jnp.int32), pos, bits_map=bits_map,
-                group_map=group_map, chunk_tokens=self.spec.chunk_tokens,
-                use_fused=self._use_fused, interpret=None)
+                group_map=group_map, chunk_tokens=self.spec.chunk_tokens)
             tok = int(np.argmax(np.asarray(lg[0])[:cfg.vocab_size]))
             out.append(tok)
         return out

@@ -9,10 +9,10 @@ identity boundary as uint16 words (bit-identical); JAX views them back on the
 way in.  Quantized codecs instead receive the *typed* arrays (ml_dtypes
 handles bf16 on the host) because quantization needs values, not bits.
 
-Decode paths: the identity codec is a bit view (never a value cast).  The
-quantized codecs dequantize through the fused Pallas kernel when the jax
-build supports it (`kernels.ops.dequant_supported`), else through the numpy
-reference (`codec.ref`).
+Decode paths: the identity codec is a bit view (never a value cast).  On the
+device path the quantized codecs dequantize through the fused Pallas
+kernels; the host path (`layer_payload_to_kv`) uses the numpy reference
+(`codec.ref`).
 """
 from __future__ import annotations
 
@@ -94,13 +94,12 @@ def layer_payload_to_device_kv(payload: bytes, num_chunks: int, spec: KVSpec,
     For quantized codecs this uploads the *compressed* tensors (int8/packed
     int4 + fp16 scales, possibly group-wise) and runs the fused Pallas
     dequant kernel, so the host->device copy moves wire bytes, not decoded
-    bytes.  Falls back to the numpy reference when the kernel API is
-    unavailable on this build."""
+    bytes.  The identity codec is a bit view."""
     codec = get_codec(spec.codec)
     G = spec.chunk_tokens
     P = num_chunks * G
     shape = (P, spec.num_kv_heads, spec.head_dim)
-    if codec.lossless or not kernel_ops.dequant_supported():
+    if codec.lossless:
         k, v = layer_payload_to_kv(payload, num_chunks, spec, dtype, layer)
         return jnp.asarray(k), jnp.asarray(v)
     q, scales = codec.parse_layer_payload(payload, num_chunks, spec, layer)

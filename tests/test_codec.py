@@ -289,8 +289,6 @@ class TestDescriptorAndAggregation:
 # ---------------------------------------------------------------------------
 # fused dequant kernels vs the numpy reference
 # ---------------------------------------------------------------------------
-@pytest.mark.skipif(not kernel_ops.dequant_supported(),
-                    reason="Pallas dequant kernels unavailable on this build")
 class TestDequantKernels:
     @pytest.mark.parametrize("N,R,W", [(1, 8, 8), (3, 16, 8), (5, 4, 128)])
     def test_int8_kernel_matches_ref(self, N, R, W):

@@ -11,25 +11,6 @@ from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.kv_gather import kv_gather
 
-
-def _pallas_unavailable_reason():
-    """Capability probe: run one trivial kernel in interpret mode.  The
-    kernels target the Pallas-TPU API surface (e.g. ``pltpu.CompilerParams``),
-    which older / CPU-only jax builds do not ship — the guard keys on the
-    actual failure, not on a version string."""
-    try:
-        pool = jnp.zeros((2, 1, 4), jnp.float32)
-        kv_gather(pool, jnp.array([0], jnp.int32), interpret=True)
-        return None
-    except Exception as e:  # pragma: no cover - environment dependent
-        return f"{type(e).__name__}: {e}"
-
-
-_REASON = _pallas_unavailable_reason()
-pytestmark = pytest.mark.skipif(
-    _REASON is not None,
-    reason=f"Pallas-TPU kernel API unavailable on this jax build: {_REASON}")
-
 KEY = jax.random.PRNGKey(0)
 
 

@@ -32,30 +32,15 @@ from repro.kernels.decode_attention import (decode_attention,
                                             decode_attention_quant,
                                             quant_block_s)
 from repro.kernels.flash_attention import flash_attention_quant
-from repro.kernels.kv_gather import kv_gather
 from repro.kernels.residency import (cache_bytes, composed_decode_hbm_traffic,
                                      fused_decode_hbm_reads, residency_ratio)
 from repro.models import build_model
 from repro.serving import (AsyncEngine, AsyncRequest, Orchestrator,
                            ServingEngine)
+from repro.serving import kv_chunks
 from repro.serving.kv_chunks import (_dequant_op_for, layer_payload_to_kv,
                                      layer_payload_to_packed_kv,
                                      packed_layer_to_fp)
-
-
-def _pallas_unavailable_reason():
-    try:
-        pool = jnp.zeros((2, 1, 4), jnp.float32)
-        kv_gather(pool, jnp.array([0], jnp.int32), interpret=True)
-        return None
-    except Exception as e:  # pragma: no cover - environment dependent
-        return f"{type(e).__name__}: {e}"
-
-
-_REASON = _pallas_unavailable_reason()
-pytestmark = pytest.mark.skipif(
-    _REASON is not None,
-    reason=f"Pallas-TPU kernel API unavailable on this jax build: {_REASON}")
 
 G = 8  # engine-level chunk tokens
 # the ISSUE's fused-vs-composed bar: bit-level agreement up to fp32
@@ -88,6 +73,7 @@ class TestFusedDecodeAttention:
         (2, 8, 4, 256, 32, 32, 256),   # GQA, block spans chunks
         (1, 4, 4, 128, 64, 32, 16),    # MHA, block inside a chunk
         (2, 4, 2, 192, 32, 64, 64),    # ragged: 192 % 64 == 0 but vary len
+        (1, 6, 3, 128, 32, 32, 64),    # odd KV: int4 packs one head a block
     ])
     def test_matches_composed(self, bits, group, B, H, KV, S, dh, G_, bs):
         rng = np.random.default_rng(hash((bits, group, S, bs)) % 2**31)
@@ -258,11 +244,23 @@ class TestDispatch:
             layer_payload_to_packed_kv(b"\0" * spec.wire_per_layer_chunk_bytes,
                                        1, spec)
 
-    def test_fused_probe_consistent(self):
-        # fused support implies standalone dequant support
-        if kernel_ops.dequant_supported(fused=True):
-            assert kernel_ops.dequant_supported()
-            assert kernel_ops.fused_attention_supported()
+    def test_kernel_failure_raises(self, monkeypatch):
+        """No fallback hides the device: a quantized payload decodes through
+        the kernel or not at all (there is no host-dequant branch)."""
+        def broken(*args, **kwargs):
+            raise RuntimeError("kernel refused")
+        monkeypatch.setitem(kv_chunks._DEQUANT_OPS, 8, broken)
+        spec = KVSpec(num_layers=1, chunk_tokens=4, num_kv_heads=1,
+                      head_dim=4, dtype_bytes=2, codec="int8")
+        payload = get_codec("int8").encode_chunk(
+            np.ones((1, 4, 4), np.float32), np.ones((1, 4, 4), np.float32),
+            spec)
+        with pytest.raises(RuntimeError, match="kernel refused"):
+            kv_chunks.layer_payload_to_device_kv(payload, 1, spec,
+                                                 jnp.float32)
+
+    def test_interpret_only_on_cpu(self):
+        assert kernel_ops._interpret() == (jax.default_backend() == "cpu")
 
 
 class TestPerLayerScaleGroups:
