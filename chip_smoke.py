@@ -247,7 +247,7 @@ def run(cfg, *, prefix: int = PREFIX, suffixes=SUFFIXES,
     d, rel = rel_err(served.logits[:V], ref_lg)
     sep = rel_err(res["control"].logits[:V], ref_lg)[1]
     report("check_packed_vs_composed", max_abs_dlogit=d, rel=rel,
-           bound=PACKED_REL_BOUND, unrelated_prompt_rel=sep)
+           rel_bound=PACKED_REL_BOUND, unrelated_prompt_rel=sep)
     if rel > PACKED_REL_BOUND:
         raise AssertionError(f"packed logits rel err {rel} > "
                              f"{PACKED_REL_BOUND}")
@@ -286,8 +286,8 @@ def run(cfg, *, prefix: int = PREFIX, suffixes=SUFFIXES,
     tie = IDENTITY_REL_BOUND * float(np.abs(ref_first).max())
     agreed = check_tokens(warm.new_tokens, ref_tokens, margins, tie)
     report("check_identity_vs_no_cache", max_abs_dlogit=d, rel=rel,
-           bound=IDENTITY_REL_BOUND, greedy_tokens_agreed=f"{agreed}/{decode}",
-           near_tie=tie)
+           rel_bound=IDENTITY_REL_BOUND,
+           greedy_tokens_agreed=f"{agreed}/{decode}", near_tie=tie)
     if rel > IDENTITY_REL_BOUND:
         raise AssertionError(f"identity logits rel err {rel} > "
                              f"{IDENTITY_REL_BOUND}")

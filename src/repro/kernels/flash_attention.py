@@ -30,11 +30,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .decode_attention import (lane_block, online_update, quant_block_s,
-                               scale_block)
+from .decode_attention import (NEG_INF, lane_block, online_update,
+                               quant_block_s, scale_block)
 from .kv_dequant import dequant_tile, int4_perm, int4_unperm, scale_bits
-
-NEG_INF = float("-inf")
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
@@ -57,7 +55,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     if causal:
         run = k_start <= q_start + block_q - 1
 
-    @pl.when(run if causal else True)
+    @pl.when(run)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)  # [bq, dh]
         k = k_ref[0, 0].astype(jnp.float32)  # [bk, dh]
