@@ -58,7 +58,7 @@ class KVCodec(ABC):
     def layer_group(self, spec: KVSpec, layer: int) -> int:
         """Scale group of layer ``layer``.  Mixed-bit maps can carry
         per-layer group sizes, so every dequant path — fused attention,
-        standalone kernel, numpy fallback — must resolve the group through
+        standalone kernel, numpy host decode — must resolve the group through
         this per layer rather than reading a codec-wide attribute once per
         payload."""
         del spec, layer

@@ -1,8 +1,8 @@
 """Numpy reference primitives for the quantized KV wire codecs.
 
 These are the ground truth the Pallas fused-dequant kernels are validated
-against (`kernels/kv_dequant.py`) and the host fallback the serving client
-uses when the kernel API is unavailable on the current jax build.
+against (`kernels/kv_dequant.py`) and the host decode of the serving
+client's host path (`serving.kv_chunks.layer_payload_to_kv`).
 
 Quantization scheme (DESIGN.md §Codec): symmetric per-channel over the token
 axis of one [tokens, width] matrix — one fp16 scale per channel (width =

@@ -41,7 +41,7 @@ def ref_decode_attention(q, k_cache, v_cache, lengths) -> jnp.ndarray:
 def ref_kv_dequant(q, scales) -> jnp.ndarray:
     """q: [N, R, W] int8; scales: [N, W] fp16 → [N, R, W] f32 — the fused
     dequant oracle (see also the numpy twin `codec.ref.dequantize_per_channel`,
-    which the serving client uses as its host fallback)."""
+    which the serving client's host path decodes with)."""
     return q.astype(jnp.float32) * scales.astype(jnp.float32)[:, None, :]
 
 
@@ -61,8 +61,8 @@ def ref_dequant_cache(q, scales, *, bits: int, group: int,
     scale rows [B, S/G, W/group] fp16 → [B, S, KV, dh].
 
     Pure jnp and jittable — this is both the fused-attention oracle's dequant
-    half and the engines' composed fallback when the fused kernels fail the
-    capability probe (dequant here, then the plain attention path)."""
+    half and the one expansion of a packed prefix to model width, where it
+    enters the batcher's fp cache (`serving.kv_chunks.packed_layer_to_fp`)."""
     B, S, KV = q.shape[0], q.shape[1], q.shape[2]
     if bits == 4:
         lo = (q & 0xF).astype(jnp.int32) - 8

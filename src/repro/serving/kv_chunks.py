@@ -122,7 +122,7 @@ class PackedLayerKV:
     integer tensors plus the per-chunk fp16 scale rows, never expanded to
     model width in HBM.  The fused attention kernels
     (`decode_attention_quant` / `flash_attention_quant`) consume exactly
-    these arrays; `kernels.ref.ref_dequant_cache` is the composed fallback.
+    these arrays; `kernels.ref.ref_dequant_cache` is their jnp reference.
     Leading batch dim is 1 (one sequence's prefix), matching the engines'
     prefix-KV convention."""
 
