@@ -57,10 +57,12 @@ class TrainSupervisor:
     def _save(self, step: int) -> None:
         if self._pending_save is not None:
             self._pending_save.join()
+            # prune only committed checkpoints: a write in flight is invisible
+            # to the listing, which would then keep one checkpoint too many
+            prune_checkpoints(self.ckpt_dir, self.keep)
         self._pending_save = save_checkpoint(
             self.ckpt_dir, step, {"params": self.params, "opt": self.opt_state},
             extra={"step": step}, async_save=True)
-        prune_checkpoints(self.ckpt_dir, self.keep)
 
     def _restore(self) -> int:
         if self._pending_save is not None:
@@ -115,6 +117,6 @@ class TrainSupervisor:
             if step % self.ckpt_every == 0:
                 self._save(step)
         self._save(num_steps)
-        if self._pending_save is not None:
-            self._pending_save.join()
+        self._pending_save.join()
+        prune_checkpoints(self.ckpt_dir, self.keep)
         return self.stats
