@@ -25,10 +25,12 @@ Checks, with bounds fixed before any chip run:
 
 Every line but the last is a report: compile time (set-up), wall seconds
 per phase (each ends in a host read of its results, i.e. after
-`block_until_ready`), and the virtual-clock TTFT, which is modelled, not
-measured.  The last line is {"ok": true, "device": {...}}.  Any failed
-phase raises, so the script exits non-zero without that line; it also
-refuses to run unless JAX's default device is a TPU.
+`block_until_ready`), each warm-trace request's wall seconds by span from
+its ``<req>/wall`` track (the tracer's waits included), and the
+virtual-clock TTFT, which is modelled, not measured.  The last line is
+{"ok": true, "device": {...}}.  Any failed phase raises, so the script
+exits non-zero without that line; it also refuses to run unless JAX's
+default device is a TPU.
 
 Run from the repository root:  python3 chip_smoke.py
 """
@@ -128,6 +130,16 @@ def check_tokens(got, ref, margins, tie: float) -> int:
     return len(got)
 
 
+def wall_seconds(tracer, req_id: str) -> dict:
+    """Seconds of each kind of wall span on the request's ``/wall`` track.
+    The kinds overlap (``slice`` lies inside ``compute``), so they are
+    reported apart and never summed."""
+    out: dict = {}
+    for s in tracer.spans(req_id + "/wall"):
+        out[f"wall_{s.name}_s"] = out.get(f"wall_{s.name}_s", 0.0) + s.dur_s
+    return dict(sorted(out.items()))
+
+
 def count_kernels(jitted, *args, **kwargs) -> int:
     text = jitted.lower(*args, **kwargs).compile().as_text()
     return text.count("tpu_custom_call")
@@ -148,6 +160,7 @@ def run(cfg, *, prefix: int = PREFIX, suffixes=SUFFIXES,
     from repro.kernels.ref import ref_dequant_cache
     from repro.launch.compile_cache import use_compile_cache
     from repro.models import build_model
+    from repro.obs import Tracer
     from repro.serving import (AsyncEngine, AsyncRequest, Orchestrator,
                                ServingEngine)
     from repro.serving.engine import ModelRunner
@@ -180,10 +193,11 @@ def run(cfg, *, prefix: int = PREFIX, suffixes=SUFFIXES,
     # -- 1 + 2: cold commit, then the warm layerwise trace ------------------
     orch = orchestrator(CODEC)
     max_seq = -(-(prefix + max(suffixes) + decode) // 512) * 512
+    tracer = Tracer()
     engine = AsyncEngine(model, params, orch, runner=runner,
                          compute=PaperComputeModel(num_layers=L),
                          num_slots=len(suffixes) + 1, max_seq=max_seq,
-                         kv_resident="packed")
+                         kv_resident="packed", tracer=tracer)
     with Phase("cold_commit", clock):
         cold = engine.serve([AsyncRequest("cold", tuple(shared), 0.0,
                                           max_new_tokens=decode)])["cold"]
@@ -218,9 +232,7 @@ def run(cfg, *, prefix: int = PREFIX, suffixes=SUFFIXES,
             raise AssertionError(f"{r.req_id}: non-finite logits")
         report(r.req_id, prompt=len(r.tokens), matched=out.matched_tokens,
                delivery=out.delivery.name if out.delivery else "recompute",
-               ttft_modelled_s=out.ttft_s,
-               wall_compute_s=out.wall_compute_s,
-               wall_upload_s=out.wall_dequant_s)
+               ttft_modelled_s=out.ttft_s, **wall_seconds(tracer, r.req_id))
 
     # -- 3: packed greedy decode, then the composed reference ---------------
     seq = ServingEngine(model, params, orch, runner=runner,

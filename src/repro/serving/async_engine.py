@@ -25,9 +25,10 @@ Two timelines compose, the same contract as `ServingEngine`:
   bit-identical to the sequential engine serving the same prompts.
 
 The *virtual* per-layer compute window ``c`` comes from the injected compute
-model (the same model the oracle uses); real wall times are recorded per
-request (and exported as ``"<req>/wall"`` spans) but never steer the virtual
-clock — that determinism is what makes the oracle comparison exact.
+model (the same model the oracle uses); real wall times are recorded only
+with a tracer attached, as spans on the ``"<req>/wall"`` and
+``"engine/wall"`` tracks (DESIGN.md §Observability), and never steer the
+virtual clock — that determinism is what makes the oracle comparison exact.
 
 Known divergences from the oracle, by design:
 
@@ -86,8 +87,6 @@ class AsyncResult:
     matched_tokens: int  # prefix tokens served from fetched payloads
     delivery: Optional[Delivery]
     record: RequestRecord  # virtual-timeline life (admit/flow_done/ttft)
-    wall_compute_s: float  # real JAX wall time spent on this request
-    wall_dequant_s: float
 
     @property
     def hit(self) -> bool:
@@ -140,8 +139,6 @@ class _Flight:
     # quantized-resident prefix (kv_resident="packed"): one PackedLayerKV per
     # layer; segs_k/segs_v then hold only this request's *suffix* KV
     packed_layers: list = dataclasses.field(default_factory=list)
-    wall_compute_s: float = 0.0
-    wall_dequant_s: float = 0.0
 
     def next_threshold(self) -> float:
         if self.mode == "chunkwise":
@@ -240,6 +237,8 @@ class AsyncEngine:
         runs per dispatched event while any decode slot is occupied (the
         continuous-batching interleave), with a final drain at the end.
         """
+        if self.tracer is not None:
+            t0 = time.perf_counter()
         self._queue = EventQueue()
         self._active: dict[str, _Flight] = {}
         self._backlog: deque = deque()
@@ -253,11 +252,15 @@ class AsyncEngine:
             self.clock.advance_to(ev.time)
             self._dispatch(ev)
             if self.batcher is not None and any(self.batcher.active):
-                self.batcher.step()
+                self.batcher.step(ev.kind.name)
         if self.batcher is not None:
             self.batcher.drain()
         for rid, sreq in self._slot_reqs.items():
             self._results[rid].new_tokens = list(sreq.tokens_out)
+        if self.tracer is not None:
+            self.tracer.span_at("engine/wall", "serve", t0,
+                                time.perf_counter(), cat="engine",
+                                requests=len(requests))
         return self._results
 
     # -- event dispatch -------------------------------------------------------
@@ -321,9 +324,15 @@ class AsyncEngine:
                                  or self._transfers < self.max_flows):
             ar, rec = self._backlog.popleft()
             self.stats.add(requests=1)
+            if self.tracer is not None:
+                t0 = time.perf_counter()
             plan = self.orch.plan(np.asarray(ar.tokens, np.int32),
                                   self._compute_hint(ar.tokens),
                                   req_id=ar.req_id)
+            if self.tracer is not None:
+                self.tracer.span_at(ar.req_id + "/wall", "plan", t0,
+                                    time.perf_counter(), cat="engine",
+                                    matched_chunks=plan.match.num_chunks)
             admitted.append((ar, rec, plan))
             self._transfers += 1
         self.peak_transfers = max(self.peak_transfers, self._transfers)
@@ -390,7 +399,13 @@ class AsyncEngine:
         # the real bytes move now (write-ahead of the virtual wire): the
         # descriptor round-trips the object store so dequant at each layer
         # crossing consumes genuine payloads
+        if self.tracer is not None:
+            t0 = time.perf_counter()
         res = self.orch.fetch(plan)
+        if self.tracer is not None:
+            self.tracer.span_at(ar.req_id + "/wall", "fetch", t0,
+                                time.perf_counter(), cat="engine", objects=m,
+                                bytes=sum(len(p) for p in res.payloads))
         layer_bytes = m * spec.mean_wire_layer_bytes
         layerwise = (plan.delivery is Delivery.LAYERWISE
                      and self._layerwise_ok)
@@ -517,45 +532,50 @@ class AsyncEngine:
 
     def _run_layer(self, fl: _Flight, l: int) -> None:
         """The real §4.2 step: layer l's payload just became consumable, so
-        dequantize it and run the jitted layer — wall-timed on the
-        ``"<req>/wall"`` track, invisible to the virtual clock."""
-        act = jnp.dtype(self.cfg.compute_dtype)
-        wall = fl.req.req_id + "/wall"
-        t0 = time.perf_counter()
+        upload it and run the jitted layer, invisible to the virtual clock.
+        Traced, each part is a wall span on ``"<req>/wall"``; the tracer
+        then also waits for the upload and the layer's weights to land."""
+        tracer = self.tracer
+        if tracer is not None:
+            t0 = time.perf_counter()
         if self.kv_resident == "packed":
             # wire image straight onto the device; no standalone dequant pass
             pkv = layer_payload_to_packed_kv(fl.payloads[l], fl.n_fetch,
                                              self.spec, layer=l)
             fl.packed_layers.append(pkv)
+            kv = pkv.as_tuple()
+        else:
+            k_d, v_d = layer_payload_to_device_kv(
+                fl.payloads[l], fl.n_fetch, self.spec,
+                jnp.dtype(self.cfg.compute_dtype), layer=l)
+            kv = (k_d[None], v_d[None])
+        if tracer is not None:
             t1 = time.perf_counter()
-            fl.wall_dequant_s += t1 - t0
+            jax.block_until_ready(kv)
+            t_up = time.perf_counter()
+            layer_p = jax.block_until_ready(self.runner.layer_params(l))
+            t_sl = time.perf_counter()
+        else:
+            layer_p = self.runner.layer_params(l)
+        if self.kv_resident == "packed":
             x, sk, sv = self.runner._layer_packed(
-                self.runner.layer_params(l), fl.x, pkv.as_tuple(),
-                fl.positions, bits=pkv.bits, group=pkv.group,
-                chunk_tokens=pkv.chunk_tokens)
-            fl.x = jax.block_until_ready(x)
-            t2 = time.perf_counter()
-            fl.wall_compute_s += t2 - t1
+                layer_p, fl.x, kv, fl.positions, bits=pkv.bits,
+                group=pkv.group, chunk_tokens=pkv.chunk_tokens)
             fl.segs_k.append(sk)  # suffix only: the prefix stays packed
             fl.segs_v.append(sv)
         else:
-            k_d, v_d = layer_payload_to_device_kv(
-                fl.payloads[l], fl.n_fetch, self.spec, act, layer=l)
-            t1 = time.perf_counter()
-            fl.wall_dequant_s += t1 - t0
-            pk, pv = k_d[None], v_d[None]
-            x, sk, sv = self.runner._layer(self.runner.layer_params(l), fl.x,
-                                           pk, pv, fl.positions)
-            fl.x = jax.block_until_ready(x)
+            x, sk, sv = self.runner._layer(layer_p, fl.x, *kv, fl.positions)
+            fl.segs_k.append(jnp.concatenate([kv[0], sk], axis=1))
+            fl.segs_v.append(jnp.concatenate([kv[1], sv], axis=1))
+        fl.x = jax.block_until_ready(x)
+        if tracer is not None:
             t2 = time.perf_counter()
-            fl.wall_compute_s += t2 - t1
-            fl.segs_k.append(jnp.concatenate([pk, sk], axis=1))
-            fl.segs_v.append(jnp.concatenate([pv, sv], axis=1))
-        if self.tracer is not None:
-            self.tracer.span_at(wall, "dequant", t0, t1, cat="engine",
-                                layer=l)
-            self.tracer.span_at(wall, "compute", t1, t2, cat="engine",
-                                layer=l)
+            wall = fl.req.req_id + "/wall"
+            tracer.span_at(wall, "dequant", t0, t1, cat="engine", layer=l)
+            tracer.span_at(wall, "upload", t0, t_up, cat="engine", layer=l,
+                           bytes=sum(a.nbytes for a in kv))
+            tracer.span_at(wall, "slice", t_up, t_sl, cat="engine", layer=l)
+            tracer.span_at(wall, "compute", t1, t2, cat="engine", layer=l)
 
     # -- completion -----------------------------------------------------------
     def _on_prefill_done(self, ev: Event) -> None:
@@ -565,7 +585,9 @@ class AsyncEngine:
         rec = fl.record
         rec.prefill_done_s = ev.time
         tokens = fl.tokens
-        t0 = time.perf_counter()
+        tracer = self.tracer
+        if tracer is not None:
+            t0 = time.perf_counter()
         if fl.mode == "recompute":
             batch = {"tokens": jnp.asarray(tokens)[None, :]}
             lg, cache = self.runner._prefill(self.runner.params, batch)
@@ -581,11 +603,13 @@ class AsyncEngine:
                                for k, v in zip(fl.segs_k, fl.segs_v)])
         packed = bool(fl.packed_layers)  # layerwise with a packed prefix
         lg = np.asarray(jax.block_until_ready(lg)[0], np.float32)
-        dt = time.perf_counter() - t0
-        fl.wall_compute_s += dt
-        if self.tracer is not None and fl.mode != "layerwise":
-            self.tracer.span_at(ev.req_id + "/wall", "compute", t0, t0 + dt,
-                                cat="engine")
+        if tracer is not None:
+            # the end is the first token's timestamp
+            wall = ev.req_id + "/wall"
+            t1 = time.perf_counter()
+            tracer.span_at(wall, "final", t0, t1, cat="engine")
+            if fl.mode != "layerwise":
+                tracer.span_at(wall, "compute", t0, t1, cat="engine")
         # write-behind commit in virtual event order: later arrivals sharing
         # the prefix hit what this request just produced.  A packed prefix
         # commits suffix chunks only — its prefix objects are already in the
@@ -595,6 +619,10 @@ class AsyncEngine:
         keys = keys_all[fl.n_fetch:] if packed else keys_all
         objs = cache_to_chunks(np.asarray(cache), keys, self.spec)
         new = self.orch.commit(tokens, objs)
+        if tracer is not None:
+            tracer.span_at(wall, "commit", t1, time.perf_counter(),
+                           cat="engine", chunks=len(objs),
+                           bytes=sum(len(o) for o in objs.values()))
         self.stats.add(commits=len(new),
                        prefix_tokens_reused=fl.P,
                        tokens_computed=len(tokens) - fl.P)
@@ -609,14 +637,20 @@ class AsyncEngine:
         if self.tracer is not None:
             self._emit_request_summary(fl, ev.time)
         self._results[ev.req_id] = AsyncResult(
-            ev.req_id, lg, [], fl.P, fl.delivery, rec,
-            fl.wall_compute_s, fl.wall_dequant_s)
+            ev.req_id, lg, [], fl.P, fl.delivery, rec)
         if fl.req.max_new_tokens > 0:
             if packed:
                 # the packed->batcher boundary: decode slots pool sequences
                 # into one fp cache, so the prefix is expanded exactly once
                 # here, only for requests that actually decode
+                if tracer is not None:
+                    t0 = time.perf_counter()
                 cache = self._materialize_packed(fl, cache)
+                if tracer is not None:
+                    jax.block_until_ready(cache)
+                    tracer.span_at(wall, "materialize", t0,
+                                   time.perf_counter(), cat="engine",
+                                   bytes=cache.nbytes)
             self._enqueue_decode(fl, lg, cache)
 
     def _materialize_packed(self, fl: _Flight, seg_cache) -> jnp.ndarray:
@@ -649,7 +683,8 @@ class AsyncEngine:
         if self.batcher is None:
             self.batcher = ContinuousBatcher(self.model, self.params,
                                              self.num_slots, self.max_seq,
-                                             eos_id=self.eos_id)
+                                             eos_id=self.eos_id,
+                                             tracer=self.tracer)
         first = int(np.argmax(logits[:self.cfg.vocab_size]))
         sreq = SlotRequest(fl.req.req_id, len(fl.tokens),
                            fl.req.max_new_tokens)

@@ -4,10 +4,15 @@ A fixed-slot batch (the production pattern: decode compiles once for the slot
 count) with per-slot positions: requests enter a free slot after prefill, emit
 one token per engine step, and leave on EOS/length, freeing the slot for the
 next queued request mid-flight — no global drain between batches.
+
+With a tracer attached, every step and every slot placement is a wall-clock
+span on the ``engine/wall`` track (DESIGN.md §Observability); without one,
+the step reads no clock and waits on nothing beyond its own host read.
 """
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import deque
 from typing import Callable, Optional
 
@@ -31,7 +36,7 @@ class ContinuousBatcher:
     """Decode across ``num_slots`` concurrent requests with one jitted step."""
 
     def __init__(self, model: Model, params, num_slots: int, max_seq: int,
-                 eos_id: Optional[int] = None) -> None:
+                 eos_id: Optional[int] = None, tracer=None) -> None:
         self.model = model
         self.params = params
         self.cfg = model.cfg
@@ -43,7 +48,11 @@ class ContinuousBatcher:
         self.cur = np.zeros((num_slots,), np.int32)
         self.active: list[Optional[SlotRequest]] = [None] * num_slots
         self.queue: deque = deque()
-        self._step = jax.jit(lambda p, c, t, pos: model.decode_step(p, c, t, pos))
+        self.tracer = tracer
+
+        def batched_decode_step(p, c, t, pos):
+            return model.decode_step(p, c, t, pos)
+        self._step = jax.jit(batched_decode_step)
         self.steps = 0
 
     # ------------------------------------------------------------------
@@ -57,22 +66,35 @@ class ContinuousBatcher:
         while self.queue and None in self.active:
             slot = self.active.index(None)
             req, slot_cache, first = self.queue.popleft()
+            if self.tracer is not None:
+                t0 = time.perf_counter()
 
             def place(dst, src):
                 # dense-family KV caches: [L, 2, B, S, KV, dh]
                 S = src.shape[3]
                 return dst.at[:, :, slot, :S].set(src[:, :, 0].astype(dst.dtype))
             self.cache = jax.tree.map(place, self.cache, slot_cache)
+            if self.tracer is not None:
+                jax.block_until_ready(self.cache)
+                self.tracer.span_at("engine/wall", "admit", t0,
+                                    time.perf_counter(), cat="engine",
+                                    req_id=req.req_id)
             self.pos[slot] = req.prompt_len
             self.cur[slot] = first
             req.tokens_out.append(first)
             self.active[slot] = req
 
     # ------------------------------------------------------------------
-    def step(self) -> list[SlotRequest]:
-        """One decode step across all occupied slots; returns finished reqs."""
+    def step(self, after: str = "") -> list[SlotRequest]:
+        """One decode step across all occupied slots; returns finished reqs.
+
+        ``after`` names what ran before the step (the engine's event kind);
+        it only labels the step's span."""
         if not any(self.active):
             return []
+        if self.tracer is not None:
+            t0 = time.perf_counter()
+            served = [r.req_id for r in self.active if r is not None]
         tok = jnp.asarray(self.cur[:, None], jnp.int32)
         pos = jnp.asarray(self.pos, jnp.int32)
         lg, self.cache = self._step(self.params, self.cache, tok, pos)
@@ -97,13 +119,17 @@ class ContinuousBatcher:
                 finished.append(req)
                 self.active[s] = None
         self.steps += 1
+        if self.tracer is not None:
+            self.tracer.span_at("engine/wall", "decode_step", t0,
+                                time.perf_counter(), cat="engine",
+                                req_ids=served, after=after)
         self._admit()
         return finished
 
     def drain(self, max_steps: int = 10_000) -> list[SlotRequest]:
         done = []
         for _ in range(max_steps):
-            done += self.step()
+            done += self.step("drain")
             if not any(self.active) and not self.queue:
                 break
         return done

@@ -147,12 +147,19 @@ class ModelRunner:
         self._layer = jax.jit(layer_fn)
         self._layer_nopre = jax.jit(layer_fn_nopre)
         self._final = jax.jit(final_fn)
-        self._prefill = jax.jit(lambda p, b: model.prefill(p, b))
-        self._prefill_prefix = jax.jit(
-            lambda p, b, pk, n: model.prefill(p, b, pk, n),
-            static_argnames=("n",))
-        self._decode = jax.jit(lambda p, c, t, pos:
-                               model.decode_step(p, c, t, pos))
+        # named, so that a device trace says which step ran
+        def prefill(p, b):
+            return model.prefill(p, b)
+
+        def prefill_prefix(p, b, pk, n):
+            return model.prefill(p, b, pk, n)
+
+        def decode_step(p, c, t, pos):
+            return model.decode_step(p, c, t, pos)
+
+        self._prefill = jax.jit(prefill)
+        self._prefill_prefix = jax.jit(prefill_prefix, static_argnames=("n",))
+        self._decode = jax.jit(decode_step)
         self._layer_packed = jax.jit(
             layer_packed_fn, static_argnames=("bits", "group", "chunk_tokens"))
         self._decode_packed = jax.jit(

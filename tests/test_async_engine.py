@@ -339,3 +339,163 @@ class TestBitIdentity:
         results = eng.serve(reqs)
         assert results["r0"].matched_tokens == 0
         assert results["r1"].matched_tokens == 4 * G
+
+
+# the wall-clock vocabulary (DESIGN.md §Observability)
+REQ_WALL = {"plan", "fetch", "dequant", "upload", "slice", "compute",
+            "final", "commit"}
+ENGINE_WALL = {"serve", "decode_step", "admit"}
+
+
+def _wall_stack(tracer, resident="fp"):
+    """A sequential engine to warm prefixes and an async engine, over an
+    orchestrator whose only tracer is ``tracer`` (None: untraced); a
+    ``"packed"`` resident prefix rides the gw8/g32 codec."""
+    cfg, model, params = _model_and_params()
+    spec = _spec() if resident == "fp" else cfg.kv_spec(
+        G, dtype_bytes=jnp.dtype(cfg.compute_dtype).itemsize, codec="gw8/g32")
+    orch = Orchestrator(RadixIndex(G), Gateway(InMemoryStore()), spec,
+                        theta_bytes=0, clock=VirtualClock(), tracer=tracer)
+    seq = ServingEngine(model, params, orch, runner=_shared_runner())
+    eng = AsyncEngine(model, params, orch, compute=_compute(),
+                      runner=_shared_runner(), num_slots=2, tracer=tracer,
+                      kv_resident=resident)
+    return seq, eng
+
+
+def _wall_calls(tracer, n_calls=2, resident="fp"):
+    """An async engine over warmed prefixes, and the requests of
+    ``n_calls`` serve() calls: three warm layerwise hits and one cold
+    recompute each, every request decoding three tokens over two slots."""
+    seq, eng = _wall_stack(tracer, resident)
+    prompts = _warm_and_prompts(seq, 3)
+    cold = np.random.default_rng(9).integers(0, 200, size=3 * G + 5)
+    calls = []
+    for k in range(n_calls):
+        reqs = [AsyncRequest(f"c{k}r{i}", tuple(map(int, p)), 0.001 * i,
+                             max_new_tokens=3)
+                for i, p in enumerate(prompts)]
+        reqs.append(AsyncRequest(f"c{k}cold", tuple(map(int, cold)) + (k,),
+                                 0.002, max_new_tokens=3))
+        calls.append(reqs)
+    return eng, calls
+
+
+def _serve_calls(tracer, n_calls=2, resident="fp"):
+    eng, calls = _wall_calls(tracer, n_calls, resident)
+    return eng, [eng.serve(reqs) for reqs in calls]
+
+
+class TestWallSpans:
+    @pytest.mark.parametrize("resident", ["fp", "packed"])
+    def test_every_wall_span_lies_in_its_call(self, resident):
+        """Each span of the table is emitted (``materialize`` where a packed
+        prefix enters decode); every ``<req>/wall`` span lies inside the
+        ``engine/wall`` serve span of the call that carried the request;
+        each layer step has its upload, slice and compute."""
+        tracer = Tracer()
+        eng, results = _serve_calls(tracer, resident=resident)
+        wall = [s for s in tracer.spans() if s.track.endswith("/wall")]
+        assert {s.name for s in wall if s.track != "engine/wall"} == \
+            REQ_WALL | ({"materialize"} if resident == "packed" else set())
+        assert {s.name for s in tracer.spans("engine/wall")} == ENGINE_WALL
+        serves = tracer.spans("engine/wall", "serve")
+        assert [s.args["requests"] for s in serves] == [4, 4]
+        L = _spec().num_layers
+        for call, res in zip(serves, results):
+            for rid, r in res.items():
+                mine = tracer.spans(rid + "/wall")
+                assert mine and all(call.t0 <= s.t0 <= s.t1 <= call.t1
+                                    for s in mine)
+                names = [s.name for s in mine]
+                assert names.count("final") == names.count("commit") == 1
+                if r.delivery is None:
+                    assert "fetch" not in names
+                    continue
+                fetch, = tracer.spans(rid + "/wall", "fetch")
+                assert fetch.args["objects"] == r.matched_tokens // G
+                assert fetch.args["bytes"] > 0
+                for name in ("dequant", "upload", "slice", "compute"):
+                    assert sorted(s.args["layer"] for s in
+                                  tracer.spans(rid + "/wall", name)) \
+                        == list(range(L))
+                for c in tracer.spans(rid + "/wall", "compute"):
+                    sl, = [s for s in tracer.spans(rid + "/wall", "slice")
+                           if s.args["layer"] == c.args["layer"]]
+                    assert c.t0 <= sl.t0 <= sl.t1 <= c.t1
+                if resident == "packed":
+                    m, = tracer.spans(rid + "/wall", "materialize")
+                    commit, = tracer.spans(rid + "/wall", "commit")
+                    assert commit.t1 <= m.t0 and m.args["bytes"] > 0
+        commits = {s.track: s.args for s in wall if s.name == "commit"}
+        assert len(commits) == 8 and commits["c0cold/wall"]["chunks"] == 3
+        assert all((a["chunks"] > 0) == (a["bytes"] > 0)
+                   for a in commits.values())
+
+    def test_one_decode_step_span_per_step_with_its_tokens(self):
+        """One ``decode_step`` per batcher step; every token after a
+        request's first is stamped by exactly one step listing it, and the
+        first by the request's ``final``; one ``admit`` per request."""
+        from repro.cluster.events import EventKind
+        tracer = Tracer()
+        eng, results = _serve_calls(tracer)
+        steps = tracer.spans("engine/wall", "decode_step")
+        assert len(steps) == eng.batcher.steps > 0
+        assert {s.args["after"] for s in steps} <= \
+            {k.name for k in EventKind} | {"drain"}
+        for res in results:
+            for rid, r in res.items():
+                listed = sum(rid in s.args["req_ids"] for s in steps)
+                assert listed == len(r.new_tokens) - 1
+                assert len(tracer.spans(rid + "/wall", "final")) == 1
+        admits = [s.args["req_id"] for s in
+                  tracer.spans("engine/wall", "admit")]
+        assert sorted(admits) == sorted(r for res in results for r in res)
+
+    def test_tracer_changes_no_logit_and_no_token(self):
+        _, traced = _serve_calls(Tracer())
+        _, bare = _serve_calls(None)
+        for a, b in zip(traced, bare):
+            assert set(a) == set(b)
+            for rid in a:
+                np.testing.assert_array_equal(a[rid].logits, b[rid].logits)
+                assert a[rid].new_tokens == b[rid].new_tokens
+
+    @pytest.mark.parametrize("resident", ["fp", "packed"])
+    def test_untraced_path_reads_no_clock_and_adds_no_sync(self, monkeypatch,
+                                                          resident):
+        """Without a tracer the served path waits once per layer step and
+        once per request's final logits, as before the wall spans, and
+        never reads the clock; with one, it also waits for each layer's
+        upload and weights, each packed prefix's expansion and each slot
+        placement."""
+        from repro.serving import async_engine, batching
+
+        class NoClock:
+            @staticmethod
+            def perf_counter():
+                raise AssertionError("clock read with tracing off")
+
+        waits = []
+        real = jax.block_until_ready
+
+        def counted(x):
+            waits.append(1)
+            return real(x)
+
+        def serve(tracer):
+            eng, (reqs,) = _wall_calls(tracer, n_calls=1, resident=resident)
+            waits.clear()
+            with monkeypatch.context() as m:
+                m.setattr(jax, "block_until_ready", counted)
+                if tracer is None:
+                    m.setattr(async_engine, "time", NoClock)
+                    m.setattr(batching, "time", NoClock)
+                return eng.serve(reqs), len(waits)
+
+        res, untraced = serve(None)
+        L = _spec().num_layers
+        hits = sum(r.delivery is not None for r in res.values())
+        assert untraced == hits * L + len(res)
+        expanded = hits if resident == "packed" else 0
+        assert serve(Tracer())[1] == hits * 3 * L + 2 * len(res) + expanded

@@ -114,3 +114,37 @@ class TestSlotTurnover:
             reqs.append(r)
         b.drain()
         assert [r.tokens_out for r in reqs] == solo
+
+
+class TestWallSpans:
+    def _serve(self, tracer):
+        _, model, params = _model_and_params()
+        b = ContinuousBatcher(model, params, 2, 64, tracer=tracer)
+        rng = np.random.default_rng(6)
+        reqs = []
+        for i, n in enumerate((3, 5, 4)):
+            prompt = rng.integers(0, 200, size=16)
+            first, cache = _prefill(model, params, prompt)
+            reqs.append(SlotRequest(f"r{i}", len(prompt), n))
+            b.enqueue(reqs[-1], cache, first)
+        b.drain()
+        return b, reqs
+
+    def test_one_decode_step_span_per_step(self):
+        """Each step is one ``decode_step`` span on ``engine/wall`` listing
+        the slots that got a token, in slot order; each placement is one
+        ``admit`` span; the tokens are those of an untraced batcher."""
+        from repro.obs import Tracer
+        tracer = Tracer()
+        b, reqs = self._serve(tracer)
+        steps = tracer.spans("engine/wall", "decode_step")
+        assert len(steps) == b.steps == 5
+        # r2 takes r0's slot 0 when r0 leaves after its second step
+        assert [s.args["req_ids"] for s in steps] == [
+            ["r0", "r1"], ["r0", "r1"], ["r2", "r1"], ["r2", "r1"], ["r2"]]
+        assert {s.args["after"] for s in steps} == {"drain"}
+        assert [s.args["req_id"] for s in
+                tracer.spans("engine/wall", "admit")] == ["r0", "r1", "r2"]
+        assert all(a.t1 <= c.t0 for a, c in zip(steps, steps[1:]))
+        _, bare = self._serve(None)
+        assert [r.tokens_out for r in reqs] == [r.tokens_out for r in bare]
