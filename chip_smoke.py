@@ -311,7 +311,7 @@ def run(cfg, *, prefix: int = PREFIX, suffixes=SUFFIXES,
         pos = P + jnp.arange(sfx.shape[1])[None]
         x = runner._embed(params["embed"], sfx, pos)
         n_layer = count_kernels(
-            runner._layer_packed, runner.layer_params(0), x, pkv.as_tuple(),
+            runner._layer_packed, params["layers"], 0, x, pkv.as_tuple(),
             pos, bits=pkv.bits, group=pkv.group, chunk_tokens=pkv.chunk_tokens)
         sk = jnp.pad(seg_cache[:, 0],
                      [(0, 0)] * 2 + [(0, decode)] + [(0, 0)] * 2)

@@ -533,8 +533,10 @@ class AsyncEngine:
     def _run_layer(self, fl: _Flight, l: int) -> None:
         """The real §4.2 step: layer l's payload just became consumable, so
         upload it and run the jitted layer, invisible to the virtual clock.
-        Traced, each part is a wall span on ``"<req>/wall"``; the tracer
-        then also waits for the upload and the layer's weights to land."""
+        The jitted step slices layer l's weights from the stacked
+        parameters itself.  Traced, each part is a wall span on
+        ``"<req>/wall"``; the tracer then also waits for the upload to
+        land."""
         tracer = self.tracer
         if tracer is not None:
             t0 = time.perf_counter()
@@ -553,18 +555,16 @@ class AsyncEngine:
             t1 = time.perf_counter()
             jax.block_until_ready(kv)
             t_up = time.perf_counter()
-            layer_p = jax.block_until_ready(self.runner.layer_params(l))
-            t_sl = time.perf_counter()
-        else:
-            layer_p = self.runner.layer_params(l)
+        layers = self.runner.params["layers"]
         if self.kv_resident == "packed":
             x, sk, sv = self.runner._layer_packed(
-                layer_p, fl.x, kv, fl.positions, bits=pkv.bits,
+                layers, l, fl.x, kv, fl.positions, bits=pkv.bits,
                 group=pkv.group, chunk_tokens=pkv.chunk_tokens)
             fl.segs_k.append(sk)  # suffix only: the prefix stays packed
             fl.segs_v.append(sv)
         else:
-            x, sk, sv = self.runner._layer(layer_p, fl.x, *kv, fl.positions)
+            x, sk, sv = self.runner._layer(layers, l, fl.x, *kv,
+                                           fl.positions)
             fl.segs_k.append(jnp.concatenate([kv[0], sk], axis=1))
             fl.segs_v.append(jnp.concatenate([kv[1], sv], axis=1))
         fl.x = jax.block_until_ready(x)
@@ -574,7 +574,6 @@ class AsyncEngine:
             tracer.span_at(wall, "dequant", t0, t1, cat="engine", layer=l)
             tracer.span_at(wall, "upload", t0, t_up, cat="engine", layer=l,
                            bytes=sum(a.nbytes for a in kv))
-            tracer.span_at(wall, "slice", t_up, t_sl, cat="engine", layer=l)
             tracer.span_at(wall, "compute", t1, t2, cat="engine", layer=l)
 
     # -- completion -----------------------------------------------------------

@@ -99,27 +99,31 @@ class ModelRunner:
             del positions
             return nn.embed(embed_p, cfg, tokens)
 
-        def layer_fn(layer_p, x, pk, pv, positions):
+        # The per-layer steps take the stacked ``params["layers"]`` and a
+        # traced layer index, and slice the layer's weights inside the jit:
+        # one executable serves every layer, and the host dispatches no
+        # slice or squeeze of its own.
+        def layer_weights(layers, l):
+            return jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, l, keepdims=False),
+                layers)
+
+        def layer_fn(layers, l, x, pk, pv, positions):
+            layer_p = layer_weights(layers, l)
             if cfg.family == "moe":
                 h, seg, _ = moe.moe_block(layer_p, cfg, x, positions, (pk, pv))
             else:
                 h, seg = dense.block(layer_p, cfg, x, positions, (pk, pv))
             return h, seg[0], seg[1]
 
-        def layer_fn_nopre(layer_p, x, positions):
-            if cfg.family == "moe":
-                h, seg, _ = moe.moe_block(layer_p, cfg, x, positions)
-            else:
-                h, seg = dense.block(layer_p, cfg, x, positions)
-            return h, seg[0], seg[1]
-
         def final_fn(params, x):
             h = nn.rmsnorm(params["final_norm"], x[:, -1:, :])
             return nn.logits(params["embed"], cfg, h)[:, 0, :]
 
-        def layer_packed_fn(layer_p, x, packed_kv, positions, *, bits, group,
-                            chunk_tokens):
-            h, seg = dense.block_packed(layer_p, cfg, x, positions, packed_kv,
+        def layer_packed_fn(layers, l, x, packed_kv, positions, *, bits,
+                            group, chunk_tokens):
+            h, seg = dense.block_packed(layer_weights(layers, l), cfg, x,
+                                        positions, packed_kv,
                                         bits=bits, group=group,
                                         chunk_tokens=chunk_tokens)
             return h, seg[0], seg[1]
@@ -145,7 +149,6 @@ class ModelRunner:
 
         self._embed = jax.jit(embed_fn)
         self._layer = jax.jit(layer_fn)
-        self._layer_nopre = jax.jit(layer_fn_nopre)
         self._final = jax.jit(final_fn)
         # named, so that a device trace says which step ran
         def prefill(p, b):
@@ -165,9 +168,6 @@ class ModelRunner:
         self._decode_packed = jax.jit(
             decode_packed_fn, static_argnames=("bits_map", "group_map",
                                                "chunk_tokens"))
-
-    def layer_params(self, l: int):
-        return jax.tree.map(lambda a: a[l], self.params["layers"])
 
     def payloads_to_prefix(self, payloads, n_chunks: int, spec):
         act = jnp.dtype(self.cfg.compute_dtype)
@@ -232,12 +232,10 @@ class ServingEngine:
                                                                     params)
         self._embed = self.runner._embed
         self._layer = self.runner._layer
-        self._layer_nopre = self.runner._layer_nopre
         self._final = self.runner._final
         self._prefill = self.runner._prefill
         self._prefill_prefix = self.runner._prefill_prefix
         self._decode = self.runner._decode
-        self._layer_params = self.runner.layer_params
 
     # ------------------------------------------------------------------
     def submit(self, tokens: np.ndarray, req_id: str = "req",
@@ -363,6 +361,7 @@ class ServingEngine:
         positions = P + jnp.arange(suffix.shape[1])[None, :]
         x = self._embed(self.params["embed"], suffix, positions)
         act = jnp.dtype(cfg.compute_dtype)
+        layers = self.runner.params["layers"]
         segs_k, segs_v, compute_times = [], [], []
         for l in range(cfg.num_layers):
             # wait for the layer-ready notification (virtual transfer clock);
@@ -377,7 +376,7 @@ class ServingEngine:
                     res.payloads[l], n_chunks, self.spec, act, layer=l)
             pk, pv = k_d[None], v_d[None]
             t0 = time.perf_counter()
-            x, sk, sv = self._layer(self._layer_params(l), x, pk, pv, positions)
+            x, sk, sv = self._layer(layers, l, x, pk, pv, positions)
             x = jax.block_until_ready(x)
             dt = time.perf_counter() - t0
             compute_times.append(dt)
@@ -421,6 +420,7 @@ class ServingEngine:
         suffix = jnp.asarray(tokens[P:])[None, :]
         positions = P + jnp.arange(suffix.shape[1])[None, :]
         x = self._embed(self.params["embed"], suffix, positions)
+        layers = self.runner.params["layers"]
         packed_layers, segs_k, segs_v, compute_times = [], [], [], []
         for l in range(cfg.num_layers):
             # same "dequant" span vocabulary as the fp path (critical-path
@@ -436,7 +436,7 @@ class ServingEngine:
             packed_layers.append(pkv)
             t0 = time.perf_counter()
             x, sk, sv = self.runner._layer_packed(
-                self._layer_params(l), x, pkv.as_tuple(), positions,
+                layers, l, x, pkv.as_tuple(), positions,
                 bits=pkv.bits, group=pkv.group, chunk_tokens=pkv.chunk_tokens)
             x = jax.block_until_ready(x)
             dt = time.perf_counter() - t0

@@ -342,8 +342,8 @@ class TestBitIdentity:
 
 
 # the wall-clock vocabulary (DESIGN.md §Observability)
-REQ_WALL = {"plan", "fetch", "dequant", "upload", "slice", "compute",
-            "final", "commit"}
+REQ_WALL = {"plan", "fetch", "dequant", "upload", "compute", "final",
+            "commit"}
 ENGINE_WALL = {"serve", "decode_step", "admit"}
 
 
@@ -392,7 +392,7 @@ class TestWallSpans:
         """Each span of the table is emitted (``materialize`` where a packed
         prefix enters decode); every ``<req>/wall`` span lies inside the
         ``engine/wall`` serve span of the call that carried the request;
-        each layer step has its upload, slice and compute."""
+        each layer step has its upload and compute."""
         tracer = Tracer()
         eng, results = _serve_calls(tracer, resident=resident)
         wall = [s for s in tracer.spans() if s.track.endswith("/wall")]
@@ -415,14 +415,10 @@ class TestWallSpans:
                 fetch, = tracer.spans(rid + "/wall", "fetch")
                 assert fetch.args["objects"] == r.matched_tokens // G
                 assert fetch.args["bytes"] > 0
-                for name in ("dequant", "upload", "slice", "compute"):
+                for name in ("dequant", "upload", "compute"):
                     assert sorted(s.args["layer"] for s in
                                   tracer.spans(rid + "/wall", name)) \
                         == list(range(L))
-                for c in tracer.spans(rid + "/wall", "compute"):
-                    sl, = [s for s in tracer.spans(rid + "/wall", "slice")
-                           if s.args["layer"] == c.args["layer"]]
-                    assert c.t0 <= sl.t0 <= sl.t1 <= c.t1
                 if resident == "packed":
                     m, = tracer.spans(rid + "/wall", "materialize")
                     commit, = tracer.spans(rid + "/wall", "commit")
@@ -467,8 +463,7 @@ class TestWallSpans:
         """Without a tracer the served path waits once per layer step and
         once per request's final logits, as before the wall spans, and
         never reads the clock; with one, it also waits for each layer's
-        upload and weights, each packed prefix's expansion and each slot
-        placement."""
+        upload, each packed prefix's expansion and each slot placement."""
         from repro.serving import async_engine, batching
 
         class NoClock:
@@ -498,4 +493,4 @@ class TestWallSpans:
         hits = sum(r.delivery is not None for r in res.values())
         assert untraced == hits * L + len(res)
         expanded = hits if resident == "packed" else 0
-        assert serve(Tracer())[1] == hits * 3 * L + 2 * len(res) + expanded
+        assert serve(Tracer())[1] == hits * 2 * L + 2 * len(res) + expanded

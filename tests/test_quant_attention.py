@@ -1,6 +1,6 @@
 """Fused quantized-KV attention (DESIGN.md §Kernels).
 
-Four layers of coverage for the quantized-resident cache path:
+Five layers of coverage for the quantized-resident cache path:
 
 * kernel equality — `decode_attention_quant` / `flash_attention_quant`
   (interpret mode) vs the composed oracles built from `codec.ref`
@@ -11,7 +11,10 @@ Four layers of coverage for the quantized-resident cache path:
   and the single-HBM-pass byte model for fused decode;
 * engine parity — `ServingEngine(kv_resident="packed")` and
   `AsyncEngine(kv_resident="packed")` against the fp-resident engines and
-  the PR-5 calibrated |dlogit| bounds.
+  the PR-5 calibrated |dlogit| bounds;
+* in-jit layer slicing — the per-layer steps index the stacked weights
+  by a traced layer index: the same bits as host-sliced weights, one
+  compiled program for every layer.
 """
 import functools
 
@@ -455,3 +458,97 @@ class TestPackedAsyncEngine:
             np.testing.assert_allclose(rp[rid].logits, rf[rid].logits,
                                        rtol=0, atol=1e-4)
             assert rp[rid].new_tokens == rf[rid].new_tokens
+
+
+# ---------------------------------------------------------------------------
+# per-layer steps slice their own weights inside the jit
+# ---------------------------------------------------------------------------
+@functools.lru_cache(maxsize=None)
+def _deep_model_and_params():
+    """The smoke model at four layers, so every layer index is exercised."""
+    import dataclasses
+    cfg = dataclasses.replace(get_smoke_config("qwen3-0.6b"), num_layers=4)
+    model = build_model(cfg)
+    return cfg, model, model.init_params(jax.random.PRNGKey(5))
+
+
+class TestInJitLayerSlicing:
+    N_PREFIX, N_SUFFIX = 4 * G, G
+
+    def _inputs(self, cfg, params, seed):
+        from repro.models import layers as nn
+        rng = np.random.default_rng(seed)
+        P, S = self.N_PREFIX, self.N_SUFFIX
+        tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, size=(1, S)))
+        positions = P + jnp.arange(S)[None, :]
+        x = nn.embed(params["embed"], cfg, tokens)
+        return rng, x, positions
+
+    def _packed(self, rng, cfg, bits):
+        kq, ks, vs = _rand_packed(rng, 1, self.N_PREFIX, cfg.num_kv_heads,
+                                  cfg.head_dim, self.N_PREFIX // G, bits, 16)
+        vq, _, _ = _rand_packed(rng, 1, self.N_PREFIX, cfg.num_kv_heads,
+                                cfg.head_dim, self.N_PREFIX // G, bits, 16)
+        return kq, vq, ks, vs
+
+    @pytest.mark.parametrize("resident", ["packed-int8", "packed-int4", "fp"])
+    def test_every_layer_matches_the_block_on_host_sliced_weights(
+            self, resident):
+        """For each layer index, the runner's step (weights sliced inside
+        the jit by a traced index) gives bit-identical outputs to the same
+        block run on the layer's weights sliced on the host."""
+        from repro.models import dense
+        from repro.serving import ModelRunner
+        cfg, model, params = _deep_model_and_params()
+        runner = ModelRunner(model, params)
+        rng, x, positions = self._inputs(cfg, params, 43)
+        layers = params["layers"]
+        if resident == "fp":
+            shape = (1, self.N_PREFIX, cfg.num_kv_heads, cfg.head_dim)
+            pk = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+            pv = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+            @jax.jit
+            def want_fn(layer_p, x):
+                h, seg = dense.block(layer_p, cfg, x, positions, (pk, pv))
+                return h, seg[0], seg[1]
+
+            def got_fn(l, x):
+                return runner._layer(layers, l, x, pk, pv, positions)
+        else:
+            bits = 8 if resident == "packed-int8" else 4
+            kv = self._packed(rng, cfg, bits)
+            args = dict(bits=bits, group=16, chunk_tokens=G)
+
+            @jax.jit
+            def want_fn(layer_p, x):
+                h, seg = dense.block_packed(layer_p, cfg, x, positions, kv,
+                                            **args)
+                return h, seg[0], seg[1]
+
+            def got_fn(l, x):
+                return runner._layer_packed(layers, l, x, kv, positions,
+                                            **args)
+        for l in range(cfg.num_layers):
+            want = want_fn(jax.tree.map(lambda a: a[l], layers), x)
+            got = got_fn(l, x)
+            for g, w in zip(got, want):
+                np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+            # different weights per layer: a wrong index would show
+            x = got[0]
+
+    def test_one_packed_program_serves_every_layer(self):
+        """The layer index is traced, not static: stepping through every
+        layer compiles exactly one packed layer program for one (bits,
+        group, chunk_tokens, shape)."""
+        from repro.serving import ModelRunner
+        cfg, model, params = _deep_model_and_params()
+        runner = ModelRunner(model, params)
+        rng, x, positions = self._inputs(cfg, params, 47)
+        kv = self._packed(rng, cfg, 8)
+        for l in range(cfg.num_layers):
+            x, _, _ = runner._layer_packed(params["layers"], l, x, kv,
+                                           positions, bits=8, group=16,
+                                           chunk_tokens=G)
+        jax.block_until_ready(x)
+        assert runner._layer_packed._cache_size() == 1
