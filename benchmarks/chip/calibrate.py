@@ -48,8 +48,7 @@ def main(argv=None) -> int:
         FAULTS[args.fault](setattr)
     layout = Layout(ROOT)
     cell = layout.cell(args.workload)
-    limits = json.loads(layout.find("limits", args.workload + ".json")
-                        .read_text())
+    limits = harness.read_limits(layout, args.workload)
     for seed in args.seeds:
         traffic, system = harness.prepare(cell, seed)
         records, _, _ = harness.drive(system, traffic, args.seconds)
@@ -57,7 +56,7 @@ def main(argv=None) -> int:
         del system
         gc.collect()
         failed, program, control = harness.check(
-            params, cell.config, traffic, records, limits, seed,
+            params, cell, traffic, records, limits, seed,
             control=args.fault is None)
         line = {"seed": seed, "fault": args.fault, "failed": failed,
                 "requests": len(records), "program": program,

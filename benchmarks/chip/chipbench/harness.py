@@ -20,7 +20,7 @@ import numpy as np
 from . import peaks as peaks_mod
 from . import stats, xtrace
 from .layout import Cell, Layout
-from .reference import Reference, widest_gap
+from .reference import logit_gaps
 from .system import build, request
 from .traffic import Request, Traffic
 from .weights import make_params
@@ -37,7 +37,9 @@ class Profile:
     of a serve() call and stops at the end of the first call that ends
     ``PROFILE_S`` later, or from a timer ``PROFILE_MAX_S`` after the start,
     inside a long call.  A ``chipbench.mark`` annotation at the start ties
-    the profiler's clock to the harness's."""
+    the profiler's clock to the harness's.  The device is recorded only
+    from some 100 ms later, so the readers take the trace from its first
+    device operation on (``RunRecord.place_trace``)."""
 
     def __init__(self, log_dir: str, t0: float) -> None:
         import threading
@@ -107,7 +109,10 @@ class RunRecord:
     spans: list             # repro.obs.trace.Span records (traced runs)
     peaks: dict
     trace: Optional[xtrace.Trace] = None
-    traced: tuple = ()      # (start, stop) of the trace, profiler clock
+    # (start, stop) of the trace on the profiler's clock, from the first
+    # device operation recorded: the device is recorded only some 100 ms
+    # after the trace starts
+    traced: tuple = ()
     offset: float = 0.0     # profiler clock - harness clock
     profiled: tuple = ()    # (start, stop) of the trace, harness clock
 
@@ -135,6 +140,18 @@ class RunRecord:
         """The requests whose life, from due to served, misses the trace."""
         lo, hi = self.profiled or (float("inf"), float("inf"))
         return [r for r in self.requests if r["end"] < lo or r["due"] > hi]
+
+    def place_trace(self, trace: xtrace.Trace, start_s: float, stop_s: float,
+                    mark_s: float) -> None:
+        """Attach the trace of the harness-clock stretch ``start_s`` to
+        ``stop_s``, tying the clocks by the mark annotation made at
+        ``mark_s``."""
+        self.trace, self.profiled = trace, (start_s, stop_s)
+        self.offset = trace.annotations(MARK_ANNOTATION)[0][0] - mark_s
+        start = start_s + self.offset
+        recorded = xtrace.recorded_from(trace)
+        self.traced = (start if recorded is None else max(start, recorded),
+                       stop_s + self.offset)
 
     def busy_windows(self) -> list:
         """The serve() calls inside the trace, on the profiler's clock."""
@@ -201,7 +218,7 @@ def prepare(cell: Cell, seed: int, tracer=None):
     t = time.perf_counter()
     conf = cell.config
     traffic = Traffic(cell.traffic, conf["vocab_size"], seed)
-    system = build(conf, lambda m: make_params(m, seed), traffic.engine,
+    system = build(cell, lambda m: make_params(m, seed), traffic.engine,
                    tracer)
     built = time.perf_counter()
     commit_documents(system, traffic,
@@ -309,19 +326,42 @@ def sample(records: list, served_tokens: int, seed: int) -> list:
     return out
 
 
-def compare(params, conf: dict, traffic: Traffic, chosen: list,
+# what a limits file may bound, over the gaps of every compared token
+GAP_STATS = {"max_logit_gap": np.max, "mean_logit_gap": np.mean}
+
+
+def read_limits(layout: Layout, workload: str) -> dict:
+    """The cell's ``limits/<cell>.json``: ``sample_served_tokens`` and a
+    limit on one statistic of ``GAP_STATS`` or more, and no other key.  A
+    file that bounds no gap, or names a key that nothing reads, is refused
+    at set-up: ``correct`` would not judge the logits."""
+    path = layout.find("limits", workload + ".json")
+    limits = json.loads(path.read_text())
+    unknown = sorted(set(limits) - set(GAP_STATS) - {"sample_served_tokens"})
+    if unknown or "sample_served_tokens" not in limits or not (
+            set(GAP_STATS) & set(limits)):
+        raise ValueError(
+            f"{path} has to give sample_served_tokens and bound one of "
+            f"{sorted(GAP_STATS)} or more, and nothing else; it gives "
+            f"{sorted(limits)}" + (f" (unknown: {unknown})" if unknown
+                                   else ""))
+    return limits
+
+
+def compare(params, cell: Cell, traffic: Traffic, chosen: list,
             control: bool = False) -> dict:
-    """The widest gap by which a served token's reference logit lies below
-    the reference's best, over every served token of ``chosen``.  Returns
-    ``{"program": gap}``, and with ``control`` also ``"control"``: the gap
-    of the fp8 reference put in the program's place, read at the same
+    """The gap by which each served token's reference logit lies below the
+    reference's best, over every served token of ``chosen``.  Returns
+    ``{"program": gaps}``, and with ``control`` also ``"control"``: the
+    gaps of the fp8 reference put in the program's place, read at the same
     positions of the same prompts and served tokens (the tokens it puts
     first there)."""
     doc_len = len(traffic.documents[0])
+    conf, Reference = cell.config, cell.arch.Reference
     ref = Reference(params, conf)
     low = Reference(params, conf, quant="fp8") if control else None
-    out = dict.fromkeys(("program", "control") if control else ("program",),
-                        0.0)
+    out = {side: [np.zeros(0, np.float32)]
+           for side in (("program", "control") if control else ("program",))}
 
     for doc in sorted({r["doc"] for r in chosen}):
         pk = ref.prefix_kv(traffic.documents[doc])
@@ -331,33 +371,36 @@ def compare(params, conf: dict, traffic: Traffic, chosen: list,
                 np.int32)
             at = (doc_len, fed, r["suffix"] - 1, len(r["served"]))
             lg = ref.logits(pk, *at)
-            out["program"] = max(out["program"],
-                                 widest_gap(lg, r["served"]))
+            out["program"].append(logit_gaps(lg, r["served"]))
             if control:
                 first = np.asarray(low.logits(pk_low, *at).argmax(-1))
-                out["control"] = max(out["control"], widest_gap(lg, first))
+                out["control"].append(logit_gaps(lg, first))
         del pk, pk_low
-    return out
+    return {side: np.concatenate(g) for side, g in out.items()}
 
 
-def check(params, conf: dict, traffic: Traffic, records: list, limits: dict,
+def gap_checks(gaps: np.ndarray, limits: dict) -> dict:
+    """Each statistic of ``GAP_STATS`` that ``limits`` bounds, read over
+    ``gaps``, beside its limit."""
+    return {name: {"value": float(stat(gaps)) if len(gaps) else 0.0,
+                   "limit": limits[name]}
+            for name, stat in GAP_STATS.items() if name in limits}
+
+
+def check(params, cell: Cell, traffic: Traffic, records: list, limits: dict,
           seed: int, control: bool = False):
     """(failed requests, each compared number with its limit, and with
     ``control`` the same numbers with the fp8 control in the program's
     place, else None)."""
     chosen = sample(records, limits["sample_served_tokens"], seed)
-    read = compare(params, conf, traffic, chosen, control)
+    read = compare(params, cell, traffic, chosen, control)
     good = [r for r in records if r["served"] is not None]
 
     def checks(side):
-        return {
-            "max_logit_gap": {"value": read[side],
-                              "limit": limits["max_logit_gap"]},
-            "served_tokens_compared": {
-                "value": sum(len(r["served"]) for r in chosen),
-                "limit": min(limits["sample_served_tokens"],
-                             sum(len(r["served"]) for r in good))},
-        }
+        return dict(gap_checks(read[side], limits), served_tokens_compared={
+            "value": sum(len(r["served"]) for r in chosen),
+            "limit": min(limits["sample_served_tokens"],
+                         sum(len(r["served"]) for r in good))})
     return (count_failed(traffic, records), checks("program"),
             checks("control") if control else None)
 
@@ -373,6 +416,7 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool, *,
     device does not suit the cell (nothing was measured)."""
     layout = Layout(root)
     cell = layout.cell(workload)
+    limits = read_limits(layout, workload)
     import jax
 
     devices = jax.devices()
@@ -390,9 +434,6 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool, *,
     # every program, however quick to compile, is cached: steady set-up
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     counter = CompileCounter()
-    conf = cell.config
-    limits = json.loads(layout.find("limits", workload + ".json")
-                        .read_text())
     # the program's spans only where a metric of the cell reads them
     spans = any(m["source"] == "program_span" for m in cell.per_layer)
     tracer = Tracer() if trace and spans else None
@@ -422,12 +463,9 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool, *,
                     f"no serve() call started after {PROFILE_AT * seconds} s "
                     f"of the window, so nothing was traced")
             t = time.perf_counter()
-            rec.profiled = (prof.start_s, prof.stop_s)
-            rec.trace = xtrace.load(prof_dir, (SERVE_ANNOTATION,
-                                               MARK_ANNOTATION))
-            mark = rec.trace.annotations(MARK_ANNOTATION)[0][0]
-            rec.offset = mark - prof.mark_s
-            rec.traced = (prof.start_s + rec.offset, prof.stop_s + rec.offset)
+            rec.place_trace(xtrace.load(prof_dir, (SERVE_ANNOTATION,
+                                                   MARK_ANNOTATION)),
+                            prof.start_s, prof.stop_s, prof.mark_s)
             quiet = rec.quiet_calls()
 
             def per_request(ix):
@@ -437,6 +475,8 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool, *,
             # what the profiler costs: call seconds per request with it on
             _log(f"[trace] read_s={time.perf_counter() - t} "
                  f"traced_s={prof.stop_s - prof.start_s} "
+                 f"device_recorded_after_s="
+                 f"{rec.traced[0] - rec.offset - prof.start_s} "
                  f"whole_calls={len(rec.profiled_calls())} "
                  f"s_per_request_traced="
                  f"{per_request(set(range(len(calls))) - quiet)} "
@@ -448,7 +488,7 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool, *,
     del system
     gc.collect()
     t = time.perf_counter()
-    failed, checks, _ = check(params, conf, traffic, records, limits, seed)
+    failed, checks, _ = check(params, cell, traffic, records, limits, seed)
     _log(f"[check] reference_s={time.perf_counter() - t} failed={failed}")
     wanted = cell.per_layer if trace else cell.end_to_end
     metrics = {}

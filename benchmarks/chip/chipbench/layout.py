@@ -2,18 +2,19 @@
 
 Everything is data: ``BENCHMARK.json`` at the repository root names the
 cells and metrics; a configuration is the file its entry names, and
-``traffic/<mix>.json``, ``limits/<cell>.json`` (the correctness limit) and
-``metrics/<name>.py`` are looked up in the directories that ``paths``
-lists, in order.  A new cell, mix or metric is new files and new entries,
-never an edit.
+``traffic/<mix>.json``, ``limits/<cell>.json`` (the correctness limit),
+``metrics/<name>.py`` and ``archs/<model_type>.py`` (see ``arch.py``) are
+looked up in the directories that ``paths`` lists, in order.  A new cell,
+mix, metric or architecture is new files and new entries, never an edit.
 """
 from __future__ import annotations
 
 import dataclasses
-import importlib.util
 import json
 import pathlib
 from types import ModuleType
+
+from . import arch
 
 
 @dataclasses.dataclass(frozen=True)
@@ -21,6 +22,7 @@ class Cell:
     name: str
     chips: int
     config: dict          # the configuration file's contents
+    arch: ModuleType      # its model_type's module (see arch.py)
     traffic: dict         # the traffic file's contents
     end_to_end: list      # BENCHMARK.json metric entries this cell reports
     per_layer: list
@@ -59,16 +61,12 @@ class Layout:
         per_layer = [m for m in self.bench["per_layer"]
                      if (workload in m["workloads"] if "workloads" in m
                          else m["moves"] in names)]
-        return Cell(workload, int(w["chips"]), config, traffic, e2e,
+        return Cell(workload, int(w["chips"]), config,
+                    arch.load(config["model_type"], self.dirs), traffic, e2e,
                     per_layer)
 
     def metric(self, name: str) -> ModuleType:
         """The reader module ``metrics/<name>.py``; it defines
         ``read(run) -> float | None`` (None: nothing to read)."""
-        path = self.find("metrics", name + ".py")
-        spec = importlib.util.spec_from_file_location(
-            "chipbench_metric_" + name.replace(".", "_").replace("-", "_"),
-            path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
+        return arch.load_module(self.find("metrics", name + ".py"),
+                                "chipbench_metric_" + name)

@@ -1,44 +1,39 @@
-"""The plain reference that decides ``correct``, and its lower-precision
-control.
+"""What every architecture's plain reference shares: the rounding of the
+stated wire codec, the fp8 rounding of the control, RMSNorm, causal
+attention in blocks of query rows, the output head in blocks of the
+vocabulary, and the logit gaps that are compared.
 
-A Qwen3-style decoder written from the configuration file alone: RMSNorm
-(eps from the file), per-head q/k RMSNorm, rotary embeddings on the two
-halves of each head, grouped-query causal attention, SwiGLU, and a tied or
-separate output table.  It imports nothing of the program; it reads the
-weights that ``weights.make_params`` made, by their names in the tree.
+Each architecture's reference is ``archs/<model_type>.py``'s
+``Reference``; it is written from the configuration file alone, imports
+nothing of the program, and runs in float32 with ``highest`` matmul
+precision, one layer at a time.
 
-It runs in float32 with ``highest`` matmul precision, one layer at a time,
-one sequence at a time.  A document's keys and values are computed once and
-reused for every request on that document: the same arithmetic as one pass
-over the whole prompt, at a fraction of the cost.
+The codec rounds per chunk of ``chunk_tokens`` tokens and per ``group``
+consecutive channels of the flattened heads, one scale = absmax /
+(2^(bits-1) - 1) rounded to float16, values rounded half to even and
+clipped to +-(2^(bits-1) - 1).
 
-The configuration states how a document is committed (``serving``:
-``commit_section_tokens``) and how its keys and values cross the wire
-(``bits``, ``group``, ``chunk_tokens``), and the reference applies both
-from their definitions.  Each section attends to the rounded sections
-before it, as a warm hit does; the rounding is per chunk of
-``chunk_tokens`` tokens and per ``group`` consecutive channels of the
-flattened heads, one scale = absmax / (2^(bits-1) - 1) rounded to float16,
-values rounded half to even and clipped to +-(2^(bits-1) - 1).  The keys
-are rounded after the rotary embedding.  Suffix and decoded tokens keep
-full precision, as a served request keeps them.
-
-``quant="fp8"`` is the control: every matmul operand (weights, activations,
-queries, keys, values, attention probabilities) is rounded to float8 e4m3
-with a per-tensor scale before the product, the next precision below the
-bfloat16 the configurations state.
+``quant="fp8"`` is the control: every matmul operand (weights,
+activations, queries, keys, values, attention probabilities) is rounded
+to float8 e4m3 with a per-tensor scale before the product, the next
+precision below the bfloat16 the configurations state.  Where attention
+runs in several blocks of query rows, the probabilities take one scale
+per block.
 """
 from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 VOCAB_BLOCK = 16384
+# float32 scores in one block of query rows (1 GiB): the largest a cell
+# held before attention was blocked (qwen3-0.6b's 4,096-token section,
+# 16 x 4096 x 4096), so those cells run in one block
+SCORE_BLOCK = 1 << 28
 
 
 def _fq(x, quant):
@@ -52,8 +47,9 @@ def _fq(x, quant):
 
 
 def codec_round(x, bits: int, group: int, chunk: int):
-    """x [P, KV, dh] -> x quantized and dequantized as the stated codec
-    does (see the module docstring)."""
+    """x [P, ...] -> x quantized and dequantized as the stated codec does
+    (see the module docstring); the channels after the first axis are
+    flattened into groups."""
     P = x.shape[0]
     qmax = float((1 << (bits - 1)) - 1)
     xg = x.reshape(P // chunk, chunk, -1, group)
@@ -71,46 +67,32 @@ def _rms(x, scale, eps):
     return x * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
 
 
-def _rope(x, positions, theta):
-    half = x.shape[-1] // 2
-    freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = positions[:, None].astype(jnp.float32) * freqs        # [S, half]
-    cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]     # [S, 1, half]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+def causal_attention(q, k, v, quant=None):
+    """Softmax attention of queries q [S, G, R, dh] (R query heads to each
+    of G key heads) at the last S of the T positions of keys k and values
+    v [T, G, dv], each query seeing the positions up to its own.  Returns
+    [S, G, R, dv].  The rows run in as few equal blocks as keep a block's
+    scores (G * R * rows * T) within ``SCORE_BLOCK``."""
+    S, G, R, dh = q.shape
+    T = k.shape[0]
+    P = T - S
+    q, k, v = _fq(q, quant), _fq(k, quant), _fq(v, quant)
 
+    def block(qb, lo):
+        s = jnp.einsum("sgrd,tgd->grst", qb, k)
+        s = s / math.sqrt(dh)
+        visible = (jnp.arange(T)[None, :]
+                   <= (P + lo + jnp.arange(qb.shape[0]))[:, None])
+        s = jnp.where(visible, s, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("grst,tgd->sgrd", _fq(p, quant), v)
 
-@functools.partial(jax.jit, static_argnames=("dims", "quant"))
-def _layer(lp, x, pk, pv, positions, *, dims, quant):
-    """x [S, d] f32; pk/pv [P, KV, dh] f32 (P may be 0).  Returns x and this
-    segment's post-rotary k, v."""
-    H, KV, dh, eps, theta = dims
-    S = x.shape[0]
-
-    def mm(a, w):
-        return jnp.matmul(_fq(a, quant), _fq(w.astype(jnp.float32), quant))
-
-    at, ml = lp["attn"], lp["mlp"]
-    h = _rms(x, lp["ln1"]["scale"], eps)
-    q = mm(h, at["wq"]["w"]).reshape(S, H, dh)
-    k = mm(h, at["wk"]["w"]).reshape(S, KV, dh)
-    v = mm(h, at["wv"]["w"]).reshape(S, KV, dh)
-    q = _rope(_rms(q, at["q_norm"]["scale"], eps), positions, theta)
-    k = _rope(_rms(k, at["k_norm"]["scale"], eps), positions, theta)
-    K = jnp.concatenate([pk, k], 0)
-    V = jnp.concatenate([pv, v], 0)
-    P = pk.shape[0]
-    qg = q.reshape(S, KV, H // KV, dh)
-    s = jnp.einsum("sgrd,tgd->grst", _fq(qg, quant), _fq(K, quant))
-    s = s / math.sqrt(dh)
-    visible = jnp.arange(P + S)[None, :] <= (P + jnp.arange(S))[:, None]
-    s = jnp.where(visible, s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("grst,tgd->sgrd", _fq(p, quant), _fq(V, quant))
-    x = x + mm(o.reshape(S, H * dh), at["wo"]["w"])
-    h = _rms(x, lp["ln2"]["scale"], eps)
-    g = jax.nn.silu(mm(h, ml["wi_gate"]["w"])) * mm(h, ml["wi_up"]["w"])
-    return x + mm(g, ml["wo"]["w"]), k, v
+    blocks = -(-G * R * S * T // SCORE_BLOCK)
+    if blocks <= 1:
+        return block(q, 0)
+    rows = -(-S // blocks)
+    return jnp.concatenate([block(q[lo:lo + rows], lo)
+                            for lo in range(0, S, rows)])
 
 
 @functools.partial(jax.jit, static_argnames=("size", "eps", "quant"))
@@ -121,88 +103,37 @@ def _head_block(x, final_scale, table, start, *, size, eps, quant):
     return jnp.matmul(_fq(h, quant), _fq(blk.astype(jnp.float32), quant).T)
 
 
-class Reference:
-    def __init__(self, params, conf: dict, quant: Optional[str] = None):
-        self.params = params
-        self.quant = quant
-        self.L = int(conf["num_hidden_layers"])
-        self.V = int(conf["vocab_size"])
-        self.dims = (int(conf["num_attention_heads"]),
-                     int(conf["num_key_value_heads"]), int(conf["head_dim"]),
-                     float(conf["rms_norm_eps"]), float(conf["rope_theta"]))
-        emb = params["embed"]
-        self.table = emb["table"]
-        if conf["tie_word_embeddings"] == ("unembed" in emb):
-            raise ValueError("the parameter tree does not match "
-                             "tie_word_embeddings")
-        self.out_table = emb.get("unembed", emb["table"])
-        dh = self.dims[2]
-        self._empty = jnp.zeros((0, self.dims[1], dh), jnp.float32)
-        sv = conf["serving"]
-        self.codec = (int(sv["bits"]), int(sv["group"]),
-                      int(sv["chunk_tokens"]))
-        self.section = int(sv["commit_section_tokens"])
-
-    def _layers(self, tokens, positions, prefix):
-        x = jnp.take(self.table, jnp.asarray(tokens), axis=0).astype(
-            jnp.float32)
-        pos = jnp.asarray(positions, jnp.int32)
-        kv = []
-        with jax.default_matmul_precision("highest"):
-            for l in range(self.L):
-                lp = jax.tree.map(lambda a: a[l], self.params["layers"])
-                pk, pv = prefix[l] if prefix is not None else (self._empty,
-                                                               self._empty)
-                x, k, v = _layer(lp, x, pk, pv, pos, dims=self.dims,
-                                 quant=self.quant)
-                kv.append((k, v))
-        return x, kv
-
-    def prefix_kv(self, tokens: np.ndarray):
-        """Per-layer (k, v) [P, KV, dh] of a document at positions 0..P-1
-        as it was committed: section by section (``commit_section_tokens``),
-        each section attending to the rounded sections before it, and each
-        rounded as the stated codec rounds a committed chunk."""
-        rnd = jax.jit(lambda a: codec_round(a, *self.codec))
-        kv = None
-        for lo in range(0, len(tokens), self.section):
-            hi = lo + self.section
-            _, seg = self._layers(tokens[lo:hi], np.arange(lo, hi), kv)
-            seg = [(rnd(k), rnd(v)) for k, v in seg]
-            kv = seg if kv is None else [
-                (jnp.concatenate([pk, k]), jnp.concatenate([pv, v]))
-                for (pk, pv), (k, v) in zip(kv, seg)]
-        return kv
-
-    def logits(self, prefix, P: int, tokens: np.ndarray, first: int,
-               n: int) -> jnp.ndarray:
-        """Logits [n, V] at rows ``first .. first+n-1`` of ``tokens``, which
-        follow a ``P``-token prefix whose per-layer kv is ``prefix``.  The
-        rows are padded to a power of two (at least 64) so that few shapes
-        compile; causality keeps the padding out of every real row."""
-        S = len(tokens)
-        Sp = max(64, 1 << (S - 1).bit_length())
-        padded = np.zeros(Sp, np.int32)
-        padded[:S] = tokens
-        x, _ = self._layers(padded, P + np.arange(Sp), prefix)
-        rows = x[first:first + n]
-        size = min(VOCAB_BLOCK, self.V)
-        starts = list(range(0, self.V - size, size)) + [self.V - size]
-        pieces, end = [], 0
-        with jax.default_matmul_precision("highest"):
-            for s in starts:
-                lg = _head_block(rows, self.params["final_norm"]["scale"],
-                                 self.out_table, s, size=size,
-                                 eps=self.dims[3], quant=self.quant)
-                pieces.append(lg[:, end - s:])
-                end = s + size
-        return jnp.concatenate(pieces, axis=1)
+def head(rows, final_scale, table, V: int, eps: float,
+         quant=None) -> jnp.ndarray:
+    """Logits [n, V] of the final hidden rows [n, d] through the final
+    RMSNorm and the first ``V`` rows of the output table, ``VOCAB_BLOCK``
+    rows at a time."""
+    size = min(VOCAB_BLOCK, V)
+    starts = list(range(0, V - size, size)) + [V - size]
+    pieces, end = [], 0
+    with jax.default_matmul_precision("highest"):
+        for s in starts:
+            lg = _head_block(rows, final_scale, table, s, size=size, eps=eps,
+                             quant=quant)
+            pieces.append(lg[:, end - s:])
+            end = s + size
+    return jnp.concatenate(pieces, axis=1)
 
 
-def widest_gap(ref_logits: jnp.ndarray, tokens) -> float:
-    """max over rows of (the reference's best logit - its logit of the
-    token that was served there)."""
+def pad_rows(tokens: np.ndarray) -> np.ndarray:
+    """``tokens`` padded with zeros to a power of two (at least 64), so that
+    few shapes compile; causality keeps the padding out of every real
+    row."""
+    S = len(tokens)
+    padded = np.zeros(max(64, 1 << (S - 1).bit_length()), np.int32)
+    padded[:S] = tokens
+    return padded
+
+
+def logit_gaps(ref_logits: jnp.ndarray, tokens) -> np.ndarray:
+    """Per row, the reference's best logit - its logit of the token that
+    was served there."""
     tok = jnp.asarray(np.asarray(tokens, np.int32))
     best = jnp.max(ref_logits, axis=-1)
     got = jnp.take_along_axis(ref_logits, tok[:, None], axis=-1)[:, 0]
-    return float(jnp.max(best - got))
+    return np.asarray(best - got)

@@ -104,6 +104,14 @@ def load(log_dir: str, annotations: tuple) -> Trace:
     return from_events(device_ops, host)
 
 
+def recorded_from(trace: Trace) -> float | None:
+    """The time from which every device is recorded: the latest of the
+    devices' first operations; None where no device has one."""
+    firsts = [min(a for _, a, _ in ops)
+              for ops in trace.device_ops.values() if ops]
+    return max(firsts) if firsts else None
+
+
 def busy_s(trace: Trace, windows) -> float:
     """Seconds inside ``windows`` in which an operation ran, averaged over
     the devices."""

@@ -24,6 +24,7 @@ CPU_PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}
 # runs read a gap of 0 on every seed tried; the fp8 control read 0.042 to
 # 0.081 over four seeds of each cell (CPU).
 TINY_LIMIT = 0.01
+TINY_MEAN_LIMIT = 0.001
 
 
 def _tiny_root(tmp: pathlib.Path, gap_limit: float) -> pathlib.Path:
@@ -173,16 +174,162 @@ def test_control_reads_not_correct(tmp_path, jax_config, workload):
 
     layout = Layout(_tiny_root(tmp_path, TINY_LIMIT))
     cell = layout.cell(workload)
-    limits = json.loads(layout.find("limits", workload + ".json").read_text())
+    limits = harness.read_limits(layout, workload)
     for seed in (SEED, 5, 2 ** 31 + 3):
         traffic, system = harness.prepare(cell, seed)
         records, _, _ = harness.drive(system, traffic, 2.0)
         failed, program, control = harness.check(
-            system.params, cell.config, traffic, records, limits, seed,
+            system.params, cell, traffic, records, limits, seed,
             control=True)
         assert failed == 0 and harness.compared_ok(program), (seed, program)
         assert not harness.compared_ok(control), (seed, control)
         assert control["max_logit_gap"]["value"] > TINY_LIMIT
+
+
+def _mean_gap_root(tmp_path) -> pathlib.Path:
+    """The tiny root, with tiny.open's limits bounding the mean gap in
+    place of the widest."""
+    root = _tiny_root(tmp_path, TINY_LIMIT)
+    (root / "extra" / "limits" / "tiny.open.json").write_text(json.dumps(
+        {"mean_logit_gap": TINY_MEAN_LIMIT, "sample_served_tokens": 40}))
+    return root
+
+
+def test_altered_token_fails_a_mean_gap_limit(tmp_path, jax_config,
+                                              monkeypatch):
+    from chipbench.faults import FAULTS
+    FAULTS["altered_token"](monkeypatch.setattr)
+    out = _run(_mean_gap_root(tmp_path), "tiny.open")
+    assert out["correct"] is False
+    assert set(out["checks"]) == {"mean_logit_gap", "served_tokens_compared"}
+    assert out["checks"]["mean_logit_gap"]["value"] > 10 * TINY_MEAN_LIMIT
+
+
+def test_control_fails_a_mean_gap_limit(tmp_path, jax_config):
+    """A limits file may bound the mean gap in place of the widest: then
+    only the mean is compared, the program reads correct and the control
+    does not, on three seeds."""
+    from chipbench import harness
+    from chipbench.layout import Layout
+
+    layout = Layout(_mean_gap_root(tmp_path))
+    cell = layout.cell("tiny.open")
+    limits = harness.read_limits(layout, "tiny.open")
+    for seed in (SEED, 5, 2 ** 31 + 3):
+        traffic, system = harness.prepare(cell, seed)
+        records, _, _ = harness.drive(system, traffic, 2.0)
+        failed, program, control = harness.check(
+            system.params, cell, traffic, records, limits, seed,
+            control=True)
+        assert set(program) == {"mean_logit_gap", "served_tokens_compared"}
+        assert failed == 0 and harness.compared_ok(program), (seed, program)
+        assert not harness.compared_ok(control), (seed, control)
+
+
+def _retype(root: pathlib.Path, model_type: str) -> dict:
+    """The tiny configuration, given ``model_type``; returns it."""
+    path = root / "extra" / "configs" / "tiny.json"
+    conf = dict(json.loads(path.read_text()), model_type=model_type)
+    path.write_text(json.dumps(conf))
+    return conf
+
+
+def test_architecture_of_new_files_runs_whole(tmp_path, jax_config):
+    """A model_type described only by a module under ``extra/archs/`` (the
+    Qwen3 equations under a new name) runs a whole traced run: its
+    program config builds the system, its reference decides ``correct``,
+    and its FLOP counts give ``decode_mfu``."""
+    from chipbench.layout import Layout
+
+    root = _tiny_root(tmp_path, TINY_LIMIT)
+    module = root / "extra" / "archs" / "tiny_decoder.py"
+    module.parent.mkdir()
+    module.write_text((HERE / "archs" / "qwen3.py").read_text())
+    _retype(root, "tiny_decoder")
+    assert not (HERE / "archs" / "tiny_decoder.py").exists()
+    assert Layout(root).cell("tiny.closed").arch.__file__ == str(module)
+    out = _run(root, "tiny.closed", trace=True)
+    assert out["correct"], out["checks"]
+    assert out["failed"] == 0 and out["attempted"] > 0
+    assert out["metrics"]["decode_mfu"]["value"] > 0
+
+
+def test_unknown_model_type_fails_at_setup_naming_the_file(tmp_path):
+    root = _tiny_root(tmp_path, TINY_LIMIT)
+    _retype(root, "absent_arch")
+    with pytest.raises(FileNotFoundError) as e:
+        _run(root, "tiny.open")
+    for d in ("extra", "chip"):
+        assert str(root / d / "archs" / "absent_arch.py") in str(e.value)
+
+
+@pytest.mark.parametrize("limits", [
+    {"sample_served_tokens": 40},
+    {"max_logit_gap_": 0.01, "sample_served_tokens": 40},
+    {"max_logit_gap": 0.01, "mean_gap": 0.001, "sample_served_tokens": 40},
+    {"max_logit_gap": 0.01}], ids=["no-bound", "misspelt", "unknown-key",
+                                   "no-sample"])
+def test_limits_that_bound_no_gap_fail_at_setup(tmp_path, monkeypatch,
+                                                limits):
+    """A limits file that bounds no gap statistic, or names a key that
+    nothing reads, would let any logits read correct: the run refuses it
+    before it builds anything."""
+    from chipbench import harness
+
+    root = _tiny_root(tmp_path, TINY_LIMIT)
+    path = root / "extra" / "limits" / "tiny.open.json"
+    path.write_text(json.dumps(limits))
+
+    def never(*args, **kwargs):
+        raise AssertionError("set-up went on past a bad limits file")
+    monkeypatch.setattr(harness, "prepare", never)
+    with pytest.raises(ValueError) as e:
+        _run(root, "tiny.open")
+    assert str(path) in str(e.value)
+
+
+def test_blocked_attention_equals_one_block(tmp_path, monkeypatch):
+    """The reference with its attention cut into blocks of query rows (the
+    score budget patched small) reads what it reads in one block: the
+    committed document's keys and values, and the logits of a suffix."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from chipbench import arch, reference
+    from chipbench.weights import make_params
+    from repro.models import build_model
+
+    conf = json.loads((_tiny_root(tmp_path, TINY_LIMIT) / "extra" / "configs"
+                       / "tiny.json").read_text())
+    source = HERE / "archs" / "qwen3.py"
+    one = arch.load_module(source, "qwen3_one_block")
+    params = make_params(build_model(one.program_config(conf)), SEED)
+    rng = np.random.default_rng(0)
+    doc = rng.integers(0, 256, 64, dtype=np.int32)   # two sections of 32
+    suffix = rng.integers(0, 256, 40, dtype=np.int32)
+
+    def read(mod):
+        ref = mod.Reference(params, conf)
+        kv = ref.prefix_kv(doc)
+        return kv, ref.logits(kv, len(doc), suffix, 0, len(suffix))
+
+    kv1, lg1 = read(one)
+    budget = 1000
+    monkeypatch.setattr(reference, "SCORE_BLOCK", budget)
+    # 2 key heads x 2 query heads each x 32 rows x 32 keys: 5 blocks
+    q = jnp.zeros((32, 2, 2, 16))
+    k = jnp.zeros((32, 2, 16))
+    jaxpr = jax.make_jaxpr(reference.causal_attention)(q, k, k)
+    assert sum(e.primitive.name == "dot_general"
+               for e in jaxpr.eqns) == 2 * 5
+    kv2, lg2 = read(arch.load_module(source, "qwen3_blocked"))
+    np.testing.assert_allclose(np.asarray(lg2), np.asarray(lg1), rtol=0,
+                               atol=1e-6)
+    for (k1, v1), (k2, v2) in zip(kv1, kv2):
+        np.testing.assert_allclose(np.asarray(k2), np.asarray(k1), rtol=0,
+                                   atol=1e-6)
+        np.testing.assert_allclose(np.asarray(v2), np.asarray(v1), rtol=0,
+                                   atol=1e-6)
 
 
 def _command(cwd, env_extra):

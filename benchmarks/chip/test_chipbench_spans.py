@@ -17,8 +17,8 @@ from test_chipbench_run import (CPU_PEAKS, SEED, TINY_LIMIT,  # noqa: F401
 HERE = pathlib.Path(__file__).resolve().parent
 PROGRAM = 100.0     # the program's clock reads the harness's + this
 PROFILER = 10.0     # the profiler's clock reads the harness's + this
-READERS = ("fetch_ms", "commit_ms", "layer_slice_ms", "upload_gb_per_s",
-           "decode_step_ms", "token_gap_p99_ms", "idle_unattributed.prefill",
+READERS = ("fetch_ms", "commit_ms", "upload_gb_per_s", "decode_step_ms",
+           "token_gap_p99_ms", "idle_unattributed.prefill",
            "idle_unattributed.decode")
 
 
@@ -97,7 +97,6 @@ def test_every_reader_on_a_hand_built_run():
     read = {n: _reader(n).read(run) for n in READERS}
     assert read["fetch_ms"] == pytest.approx(20.0)      # r0 20, r2 60
     assert read["commit_ms"] == pytest.approx(100.0)    # r0 100, r2 300
-    assert read["layer_slice_ms"] == pytest.approx(10.0)
     assert read["upload_gb_per_s"] == pytest.approx(2e8 / 0.1 / 1e9)
     assert read["decode_step_ms"] == pytest.approx(50.0)   # 50, 250 x2
     # gaps: final -> step 1 is 0.25 s, step 1 -> step 2 is 0.25 s

@@ -7,12 +7,13 @@ import pathlib
 import numpy as np
 import pytest
 
-from chipbench import flops, stats, xtrace
+from chipbench import arch, flops, stats, xtrace
 from chipbench.peaks import peaks_for
 from chipbench.traffic import Traffic, exponential_gaps
 
 HERE = pathlib.Path(__file__).resolve().parent
 MIXES = sorted((HERE / "traffic").glob("*.json"))
+QWEN3 = arch.load("qwen3", [HERE])
 
 
 def _take(traffic, n):
@@ -114,7 +115,8 @@ def _reader(name):
 
 def _run(requests, calls, conf=None, peaks=None, profiled=()):
     from chipbench.harness import RunRecord
-    return RunRecord(cell=type("C", (), {"config": conf})(), setup_s=12.5,
+    cell = type("C", (), {"config": conf, "arch": QWEN3})()
+    return RunRecord(cell=cell, setup_s=12.5,
                      requests=requests, calls=calls, spans=[], peaks=peaks,
                      profiled=profiled)
 
@@ -130,18 +132,19 @@ def test_rates_are_all_work_over_all_time():
     assert _reader("setup_s").read(run) == 12.5
 
 
-CONF = {"hidden_size": 8, "num_attention_heads": 4, "num_key_value_heads": 2,
-        "head_dim": 4, "intermediate_size": 16, "num_hidden_layers": 3,
-        "vocab_size": 10}
+CONF = {"model_type": "qwen3", "hidden_size": 8, "num_attention_heads": 4,
+        "num_key_value_heads": 2, "head_dim": 4, "intermediate_size": 16,
+        "num_hidden_layers": 3, "vocab_size": 10}
 
 
 def test_flop_counts_by_hand():
     # per layer: q 8x16, k 8x8, v 8x8, o 16x8, mlp 3 x 8x16
-    assert flops.layer_matmul_params(CONF) == 128 + 64 + 64 + 128 + 384
+    assert QWEN3.layer_matmul_params(CONF) == \
+        128 + 64 + 64 + 128 + 384
     # one decoded token at context 5: 3 layers x (2*768 + 4*4*4*5) + 2*8*10
-    assert flops.decode_flops(CONF, 5) == 3 * (1536 + 320) + 160
+    assert QWEN3.decode_flops(CONF, 5) == 3 * (1536 + 320) + 160
     # suffix of 2 after 6 cached: queries see 7 and 8 keys
-    assert flops.prefill_flops(CONF, 2, 6) == (
+    assert QWEN3.prefill_flops(CONF, 2, 6) == (
         3 * (2 * 768 * 2 + 4 * 4 * 4 * (7 + 8)) + 160)
     f, b = flops.flash_attention_quant(CONF, queries=3, keys=32, bits=4,
                                        group=4, chunk_tokens=16)
@@ -212,13 +215,56 @@ def test_host_clock_readers_skip_the_profiled_calls():
     assert quiet.quiet_calls() == {0, 2}
     assert [r["call"] for r in quiet.quiet_requests()] == [0]
     assert _reader("admission_wait_p50_ms").read(quiet) == 500.0
-    per_call = 100.0 * flops.decode_flops(conf, 10) / 1e3
+    per_call = 100.0 * QWEN3.decode_flops(conf, 10) / 1e3
     assert _reader("decode_mfu").read(quiet) == pytest.approx(
         2 * per_call / 1.5)
     assert _reader("prefill_mfu").read(quiet) == pytest.approx(
-        100.0 * 2 * flops.prefill_flops(conf, 2, 6) / (1.5 * 1e3))
+        100.0 * 2 * QWEN3.prefill_flops(conf, 2, 6) / (1.5 * 1e3))
     whole = _run(reqs, calls, conf, peaks)
     assert whole.quiet_calls() == {0, 1, 2}
     assert _reader("admission_wait_p50_ms").read(whole) == 500.0
     assert _reader("decode_mfu").read(whole) == pytest.approx(
         3 * per_call / 3.5)
+
+
+def test_trace_is_read_from_its_first_device_operation():
+    """The device is recorded only some 100 ms after the trace starts: a
+    call that starts before the first device operation is not profiled,
+    and the traced window starts at that operation."""
+    from chipbench.harness import MARK_ANNOTATION
+    offset = 10.0   # profiler clock - harness clock
+    calls = [(1.0, 1.3, 1), (1.3, 1.6, 1), (1.6, 1.9, 1)]
+    trace = xtrace.from_events(
+        {"/device:TPU:0": [("op", 1.12 + offset, 1.2 + offset),
+                           ("op", 1.4 + offset, 1.5 + offset)]},
+        [(MARK_ANNOTATION, 1.0 + offset, 1.0 + offset)])
+    run = _run([], calls)
+    run.place_trace(trace, 1.0, 2.0, 1.0)
+    assert run.offset == pytest.approx(offset)
+    assert run.profiled == (1.0, 2.0)
+    assert run.traced == pytest.approx((1.12 + offset, 2.0 + offset))
+    assert run.profiled_calls() == [1, 2]
+    # no device operation recorded: the trace is taken from its start
+    bare = xtrace.from_events({}, [(MARK_ANNOTATION, 11.0, 11.0)])
+    run.place_trace(bare, 1.0, 2.0, 1.0)
+    assert run.traced == pytest.approx((11.0, 12.0))
+    assert run.profiled_calls() == [0, 1, 2]
+
+
+def test_gap_checks_read_what_the_limits_bound():
+    import jax.numpy as jnp
+    from chipbench.harness import gap_checks
+    from chipbench.reference import logit_gaps
+
+    logits = jnp.array([[1.0, 3.0, 2.0], [0.0, 0.5, 4.0], [2.0, 1.0, 0.0]])
+    gaps = logit_gaps(logits, [1, 0, 0])       # best 3, 4, 2: gaps 0, 4, 0
+    assert list(gaps) == [0.0, 4.0, 0.0]
+    both = gap_checks(gaps, {"max_logit_gap": 1.0, "mean_logit_gap": 0.5,
+                             "sample_served_tokens": 3})
+    assert both == {"max_logit_gap": {"value": 4.0, "limit": 1.0},
+                    "mean_logit_gap": {"value": pytest.approx(4.0 / 3),
+                                       "limit": 0.5}}
+    assert list(gap_checks(gaps, {"mean_logit_gap": 0.5})) == \
+        ["mean_logit_gap"]
+    assert gap_checks(gaps[:0], {"max_logit_gap": 1.0}) == \
+        {"max_logit_gap": {"value": 0.0, "limit": 1.0}}
